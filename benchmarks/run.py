@@ -19,6 +19,8 @@ import os
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (allocator, calibration, decode_step, decode_throughput,
                degradation, fig3_trajectory, fig5_hw, kvcache, kvcache_paged,
                latency, roofline, speculative, table1_sigma_kl, table2_phases,
@@ -182,6 +184,7 @@ def compare(baseline_path: str) -> int:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--full", action="store_true", help="paper-scale budgets")
     ap.add_argument("--only", default=None, choices=sorted(SECTIONS))

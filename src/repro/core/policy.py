@@ -16,6 +16,7 @@ import dataclasses
 import enum
 import hashlib
 import json
+import re
 from typing import Iterable, Mapping
 
 import numpy as np
@@ -150,6 +151,32 @@ class BitPolicy:
             for x in d["layers"]
         )
         return cls(layers, {k: int(v) for k, v in d["bits"].items()}, int(d["act_bits"]))
+
+
+#: per-layer bits of the ``mixed`` scheme after layer 0, repeating
+MIXED_PATTERN = (4, 4, 6, 4)
+
+
+def scheme_policy(specs: Iterable[LayerInfo], scheme: str) -> BitPolicy:
+    """A fixed policy with no search behind it: ``uniform<b>``, or ``mixed``
+    — the shape of a typical SigmaQuant result (embedding and layer 0 at 8
+    bits, the other layers :data:`MIXED_PATTERN` by layer index, layers
+    without an index at the pattern's first entry).  Serving uses it where
+    no searched artifact exists, and the dry-run where no weights exist."""
+    specs = tuple(specs)
+    if scheme.startswith("uniform"):
+        return BitPolicy.uniform(specs, int(scheme.removeprefix("uniform")))
+    if scheme != "mixed":
+        raise ValueError(f"unknown policy scheme {scheme!r}")
+    bits = {}
+    for s in specs:
+        m = re.search(r"layer(\d+)", s.name)
+        idx = int(m.group(1)) if m else 0
+        if s.kind == "embedding" or (m and idx == 0):
+            bits[s.name] = 8
+        else:
+            bits[s.name] = MIXED_PATTERN[idx % len(MIXED_PATTERN)]
+    return BitPolicy.from_bits(specs, bits)
 
 
 # ---------------------------------------------------------------------------

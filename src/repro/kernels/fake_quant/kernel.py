@@ -14,9 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax<=0.4.x names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(w_ref, scale_ref, bits_ref, out_ref):
     b = bits_ref[0, 0]
@@ -55,7 +52,7 @@ def fake_quant_pallas(
         ],
         out_specs=pl.BlockSpec((bk, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((kp, np_), w.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,

@@ -20,9 +20,11 @@ instead:
     finished block once on the last K step (bn*8 multiplies instead of
     bn*bk per step).
 
-Weight lanes are unpacked exactly as in quant_matmul (lane-interleaved along
-K), so both kernels share one packed HBM layout and ``quant_matmul`` can
-dispatch here for M <= GEMV_MAX_M with no repacking.
+Weight bytes are read as lane planes (``kernels/lanes.py``): the caller's
+x is de-interleaved into ``lanes`` planes once, outside the kernel, and each
+K step takes one integer-level dot per plane.  Both kernels share one packed
+HBM layout, so ``quant_matmul`` dispatches here for M <= GEMV_MAX_M with no
+repacking.
 """
 from __future__ import annotations
 
@@ -34,24 +36,50 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import LANES
-from repro.kernels.quant_matmul.kernel import _CompilerParams, _unpack_block
+from repro.kernels.lanes import deinterleave, lane_planes, pick_tile
 
 #: largest M served by the GEMV fast path (one f32 sublane)
 GEMV_MAX_M = 8
 
 
-def _kernel(x_ref, packed_ref, scale_ref, out_ref, *, bits: int, bk: int,
-            k_steps: int):
+def compute_dtype(dtype):
+    """MXU operand dtype: bf16 activations meet bf16 levels (|level| <= 127
+    is exact in bf16, and products accumulate in f32); anything else runs
+    in f32."""
+    return jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
+
+
+def plane_dot(x_ref, packed, bits: int) -> jax.Array:
+    """sum over lane planes of x_plane @ levels_plane.T -> (rows(x), rows(w)).
+
+    ``x_ref``: (lanes, m, kp) de-interleaved activations; ``packed``: the
+    (n, kp) int8 weight block.
+    """
+    acc = None
+    for lane, lev in enumerate(lane_planes(packed, bits)):
+        x = x_ref[lane]
+        # f32 operands need the full-precision MXU passes; bf16 ones are exact
+        prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+        d = jax.lax.dot_general(
+            x, lev.astype(jnp.float32).astype(x.dtype), (((1,), (1,)), ((), ())),
+            precision=prec, preferred_element_type=jnp.float32)
+        acc = d if acc is None else acc + d
+    return acc
+
+
+def k_tile(k: int, bits: int, target: int) -> int:
+    """K block: a divisor of K whose packed width is a multiple of 128
+    lanes (the whole K where none exists, e.g. K=11008 at 2 bits)."""
+    lanes = LANES[bits]
+    return pick_tile(k // lanes, target // lanes, 128) * lanes
+
+
+def _kernel(x_ref, packed_ref, scale_ref, out_ref, *, bits: int, k_steps: int):
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    levels = _unpack_block(packed_ref[...], bits, bk)           # (bn, bk) int32
-    x = x_ref[...].astype(jnp.float32)                          # (8, bk)
-    out_ref[...] += jax.lax.dot_general(
-        x, levels.astype(jnp.float32), (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    out_ref[...] += plane_dot(x_ref, packed_ref[...], bits)     # (8, bn)
 
     @pl.when(pl.program_id(1) == k_steps - 1)
     def _apply_scale():
@@ -69,7 +97,7 @@ def quant_gemv_pallas(
     bits: int,
     k: int,
     bn: int = 256,
-    bk: int = 512,
+    bk: int = 1024,
     interpret: bool = False,
     out_dtype=None,
 ) -> jax.Array:
@@ -79,40 +107,31 @@ def quant_gemv_pallas(
     assert kx == k, (kx, k)
     if m > GEMV_MAX_M:
         raise ValueError(f"GEMV fast path is for M <= {GEMV_MAX_M}, got M={m}")
+    if k % lanes:
+        raise ValueError(f"K={k} must be divisible by lanes={lanes}")
     out_dtype = out_dtype or x.dtype
 
-    bk = min(bk, k)
-    # never reject an N the GEMM path accepted: fall back to the largest
-    # divisor (worst case the full N in one block, or narrow blocks for
-    # odd fused widths)
-    bn = _largest_divisor_leq(n, bn)
-    if k % bk or bk % lanes:
-        raise ValueError(f"K={k} must be divisible by bk={bk} (and bk by lanes={lanes})")
+    bk = k_tile(k, bits, bk)
+    bn = pick_tile(n, bn, 128)
     if m != GEMV_MAX_M:
         x = jnp.pad(x, ((0, GEMV_MAX_M - m), (0, 0)))
+    xp = deinterleave(x.astype(compute_dtype(x.dtype)), lanes, 0)  # (lanes, 8, K/lanes)
 
     k_steps = k // bk
-    grid = (n // bn, k_steps)
+    bkp = bk // lanes
     out = pl.pallas_call(
-        functools.partial(_kernel, bits=bits, bk=bk, k_steps=k_steps),
-        grid=grid,
+        functools.partial(_kernel, bits=bits, k_steps=k_steps),
+        grid=(n // bn, k_steps),
         in_specs=[
-            pl.BlockSpec((GEMV_MAX_M, bk), lambda j, kk: (0, kk)),
-            pl.BlockSpec((bn, bk // lanes), lambda j, kk: (j, kk)),
+            pl.BlockSpec((lanes, GEMV_MAX_M, bkp), lambda j, kk: (0, 0, kk)),
+            pl.BlockSpec((bn, bkp), lambda j, kk: (j, kk)),
             pl.BlockSpec((1, bn), lambda j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((GEMV_MAX_M, bn), lambda j, kk: (0, j)),
         out_shape=jax.ShapeDtypeStruct((GEMV_MAX_M, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(x, packed, scale)
+    )(xp, packed, scale)
     return out[:m].astype(out_dtype)
-
-
-def _largest_divisor_leq(n: int, target: int) -> int:
-    for d in range(min(target, n), 0, -1):
-        if n % d == 0:
-            return d
-    return 1

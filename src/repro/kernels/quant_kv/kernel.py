@@ -1,33 +1,47 @@
 """Pallas TPU kernels for the quantized decode state (DESIGN.md §11).
 
-Two kernels over the ``kvcache/cache.py`` packed layout (int8 lanes along
-head_dim, f32 scales per sequence block):
+Kernels over the ``kvcache/cache.py`` packed layout (int8 lanes along
+head_dim, f32 scales per sequence block) and its paged twin:
 
 * **fused dequant-attention** — one decode token per slot attends over the
-  packed cache.  Grid ``(B, n_kv)``: each program unpacks its head's
-  ``(S, hd/lanes)`` K and V lanes in VMEM, applies the per-block scales,
-  and runs the masked softmax for that head's query group.  The packed
-  bytes are the only state bytes that cross HBM->VMEM — the decode-state
-  analogue of the weight kernels' dequant-in-kernel contract (a W4 cache
-  moves half the bytes of W8, and decode is memory-bound on exactly those
-  bytes at long context).
+  packed cache.  Grid ``(B, n_kv)``: each program reads its head's packed K
+  and V bytes into VMEM, dequantizes them there, and runs the masked
+  softmax for that head's query group.  The packed bytes are the only
+  state bytes that cross HBM->VMEM — the decode-state analogue of the
+  weight kernels' dequant-in-kernel contract.
 
 * **quantized append** — writes one new token at a per-slot position.
   Scalar-prefetched positions drive the BlockSpec index maps, so each
-  program DMAs exactly ONE ``(H, block, hd/lanes)`` sequence block (not the
-  whole cache), dequantizes it, inserts the new row, masks positions beyond
-  the write point (container invariant: stale levels stay zero), and
-  requantizes under a fresh scale.  The kernel emits the new block + scale;
-  the thin jnp scatter that places them back is shared with the reference
-  path (ops.py).
+  program DMAs exactly ONE ``(H, block, hd/lanes)`` sequence block and its
+  scale, dequantizes it, inserts the new row, masks positions beyond the
+  write point (container invariant: stale levels stay zero), and
+  requantizes under a fresh scale.  The caller places the block back
+  (ops.py).
 
-Shapes here are the skinny decode regime: one query token, S up to a few
-thousand — the whole per-head cache block fits VMEM comfortably
-(S=4096, hd=128, int8: 512 KiB K+V).  CPU tests run ``interpret=True``.
+* **fused decode step** — append + attend in one kernel (dense, paged, and
+  with the Q/K/V projection pulled in).
+
+Layout inside the kernels:
+
+* The cache is viewed as ``(nb, block, hd/lanes)`` bytes with ``(nb, 1, 1)``
+  scales (free reshapes of the container), so a block's scale broadcasts
+  over its rows with no relayout, and the touched block is one index of the
+  leading axis.
+* Like the weight kernels, nothing is unpacked into a contiguous ``hd``
+  axis (``kernels/lanes.py``): K bytes are read as lane planes and the
+  query arrives de-interleaved into the same planes, so ``q . k`` is a sum
+  of one dot per plane; attention over V yields one output plane per V
+  lane, which the wrapper re-interleaves.
+* Every block's last two dims tile (8, 128) or span the array's own last
+  two dims: row vectors (mask, new K/V rows) carry a unit axis.
+
+CPU tests run ``interpret=True``; ``tests/test_tpu_compile.py`` compiles
+them for a described v5e.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -35,35 +49,121 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import LANES
-from repro.kernels.quant_matmul.kernel import _CompilerParams, _unpack_block
+from repro.kernels.lanes import deinterleave, interleave, lane_planes, pack_planes
+from repro.kernels.quant_gemv.kernel import plane_dot
+
+_F32 = jnp.float32
 
 
-def _pack_lanes(levels: jax.Array, bits: int) -> jax.Array:
-    """int32 levels ``(..., k)`` -> int8 lanes (k divisible by lanes).
+def _dot(a, b, contract):
+    """f32 dot at full f32 precision (the MXU default would round to bf16)."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=_F32)
 
-    The Pallas-safe inverse of ``_unpack_block`` (loop form — no iota /
-    lane-axis reduce in the kernel body); ``core/packing.pack`` is the
-    canonical layout and the append parity tests pin this bit-exact
-    against it.
+
+def _dequant(packed, col, bits: int) -> list[jax.Array]:
+    """(nb, block, hdp) packed bytes + (nb, 1, 1) block scales -> the
+    ``lanes`` dequantized (S, hdp) f32 planes."""
+    nb, block, hdp = packed.shape
+    return [(lev.astype(_F32) * col).reshape(nb * block, hdp)
+            for lev in lane_planes(packed, bits)]
+
+
+def _set_block(col: jax.Array, bidx, sc: jax.Array) -> jax.Array:
+    """(nb, 1, 1) scales with block ``bidx`` replaced by ``sc``."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, col.shape, 0)
+    return jnp.where(idx == bidx, sc, col)
+
+
+def _attend(q, kbytes, kcol, vbytes, vcol, mask, *, k_bits: int, v_bits: int,
+            hd: int) -> list[jax.Array]:
+    """The shared fused dequant-attention body.
+
+    ``q``: (lanes_k, g, hd/lanes_k) query planes; ``kbytes``/``vbytes``:
+    (nb, block, ·) packed bytes (int8, or int32 holding sign-extended
+    bytes); ``kcol``/``vcol``: (nb, 1, 1) block scales; ``mask``: (1, S)
+    additive.  Returns the ``lanes_v`` output planes, each (g, hd/lanes_v).
+    Every attention kernel (dense, paged, fused) calls it on the same
+    bytes, so they agree bitwise.
     """
-    lanes = LANES[bits]
-    if lanes == 1:
-        return levels.astype(jnp.int8)
-    grouped = levels.reshape(*levels.shape[:-1], -1, lanes)
-    mask = (1 << bits) - 1
-    out = jnp.zeros(grouped.shape[:-1], jnp.int32)
-    for lane in range(lanes):
-        out = out | ((grouped[..., lane] & mask) << (bits * lane))
-    return out.astype(jnp.uint8).astype(jnp.int8)
+    scores = None
+    for lane, k in enumerate(_dequant(kbytes, kcol, k_bits)):
+        d = _dot(q[lane].astype(_F32), k, ((1,), (1,)))
+        scores = d if scores is None else scores + d
+    scores = scores * (1.0 / math.sqrt(hd)) + mask               # (g, S)
+    m = jnp.max(scores, axis=-1, keepdims=True)
+    p = jnp.exp(scores - m)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    return [_dot(p, v, ((1,), (0,))) / l
+            for v in _dequant(vbytes, vcol, v_bits)]
 
 
-def _dequant_block(packed, scale, bits, hd, block):
-    """(S, hd/lanes) int8 + (nb, 1) scale -> (S, hd) f32 inside the kernel."""
-    lev = _unpack_block(packed, bits, hd)            # (S, hd) int32
-    s = lev.shape[0]
-    nb = s // block
-    fp = lev.astype(jnp.float32).reshape(nb, block, hd) * scale.reshape(nb, 1, 1)
-    return fp.reshape(s, hd)
+def _store_planes(out_ref, planes):
+    for lane, plane in enumerate(planes):
+        out_ref[0, 0, lane] = plane
+
+
+def _requant(old, sc_old, new, off, *, bits: int):
+    """Insert the new row at ``off`` into a packed block and requantize.
+
+    ``old``: (..., block, hdp) packed bytes; ``sc_old``: (..., 1, 1);
+    ``new``: ``lanes`` planes, each broadcastable to (..., 1, hdp).
+    Returns the new int8 bytes and (..., 1, 1) scale.  The math is
+    ``kvcache.cache.requantize_block_levels`` plane by plane, so the
+    levels match the jnp path bit for bit.
+    """
+    idx = jax.lax.broadcasted_iota(jnp.int32, old.shape, old.ndim - 2)
+    q = float(2 ** (bits - 1) - 1)
+    fps = []
+    for lev, row in zip(lane_planes(old, bits), new):
+        fp = jnp.where(idx < off, lev.astype(_F32) * sc_old, 0.0)
+        fps.append(jnp.where(idx == off, row.astype(_F32), fp))
+    amax = None
+    for fp in fps:
+        a = jnp.max(jnp.max(jnp.abs(fp), axis=-1, keepdims=True), axis=-2,
+                    keepdims=True)
+        amax = a if amax is None else jnp.maximum(amax, a)
+    sc = jnp.maximum(amax, 1e-12) / q
+    levs = [jnp.clip(jnp.round(fp / sc), -q, q).astype(jnp.int32) for fp in fps]
+    return pack_planes(levs, bits), sc
+
+
+def _new_planes(new, lanes: int):
+    """(…, lanes, 1, hdp) de-interleaved row -> list of (…, 1, hdp) planes."""
+    return [new[..., lane, :, :] for lane in range(lanes)]
+
+
+def _rows_as_planes(x: jax.Array, lanes: int) -> jax.Array:
+    """(..., hd) rows -> (..., lanes, 1, hd/lanes): planes with a unit axis."""
+    return deinterleave(x.astype(_F32)[..., None, :], lanes, x.ndim - 1)
+
+
+def _blocks(packed: jax.Array, scale: jax.Array, block: int):
+    """Container (B, H, S, hdp) bytes + (B, H, nb, 1) scales -> the free
+    (B, H, nb, block, hdp) / (B, H, nb, 1, 1) views the kernels read."""
+    b, h, s, hdp = packed.shape
+    return (packed.reshape(b, h, s // block, block, hdp),
+            scale.reshape(b, h, s // block, 1, 1))
+
+
+def _compiler_params(*semantics):
+    # the whole per-head cache sits in VMEM; allow more than the default
+    # scoped limit for long contexts
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=100 * 2**20)
+
+
+def _head_spec(shape):
+    """Block ``(1, 1, *shape)`` at (slot, head) for a grid whose first two
+    axes are (slot, head); further grid axes and scalar-prefetch refs are
+    ignored."""
+    return pl.BlockSpec((1, 1, *shape),
+                        lambda i, j, *_: (i, j) + (0,) * len(shape))
+
+
+def _mask_spec(s: int):
+    return pl.BlockSpec((1, 1, s), lambda i, *_: (i, 0, 0))
 
 
 # ---------------------------------------------------------------------------
@@ -71,31 +171,11 @@ def _dequant_block(packed, scale, bits, hd, block):
 # ---------------------------------------------------------------------------
 
 
-def _attn_math(q, kp, ks, vp, vs, mask, *, k_bits: int, v_bits: int, hd: int,
-               block: int):
-    """The shared fused dequant-attention body: packed (S, ·) K/V + per-block
-    scales -> (g, hd) output.  Both the dense kernel and the paged kernel
-    (which first gathers its table's blocks into this exact layout) call it,
-    so paged attention is bitwise-identical to dense on identical contents."""
-    q = q.astype(jnp.float32)                                 # (g, hd)
-    k = _dequant_block(kp, ks, k_bits, hd, block)             # (S, hd)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s * (hd ** -0.5) + mask                               # (g, S) + (1, S)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
-    v = _dequant_block(vp, vs, v_bits, hd, block)
-    o = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    return o / l
-
-
 def _attn_kernel(q_ref, kp_ref, ks_ref, vp_ref, vs_ref, mask_ref, out_ref, *,
-                 k_bits: int, v_bits: int, hd: int, block: int):
-    out_ref[0, 0] = _attn_math(q_ref[0, 0], kp_ref[0, 0], ks_ref[0, 0],
-                               vp_ref[0, 0], vs_ref[0, 0], mask_ref[...],
-                               k_bits=k_bits, v_bits=v_bits, hd=hd, block=block)
+                 k_bits: int, v_bits: int, hd: int):
+    _store_planes(out_ref, _attend(
+        q_ref[0, 0], kp_ref[0, 0], ks_ref[0, 0], vp_ref[0, 0], vs_ref[0, 0],
+        mask_ref[0], k_bits=k_bits, v_bits=v_bits, hd=hd))
 
 
 @functools.partial(jax.jit, static_argnames=("k_bits", "v_bits", "hd", "block",
@@ -117,25 +197,24 @@ def quant_kv_attention_pallas(
     b, n_kv, g, _ = q.shape
     s = k_packed.shape[2]
     nb = s // block
-    grid = (b, n_kv)
-    return pl.pallas_call(
-        functools.partial(_attn_kernel, k_bits=k_bits, v_bits=v_bits, hd=hd,
-                          block=block),
-        grid=grid,
+    lk, lv = LANES[k_bits], LANES[v_bits]
+    hk, hv = k_packed.shape[-1], v_packed.shape[-1]
+    out = pl.pallas_call(
+        functools.partial(_attn_kernel, k_bits=k_bits, v_bits=v_bits, hd=hd),
+        grid=(b, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, s, k_packed.shape[-1]), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, nb, 1), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, s, v_packed.shape[-1]), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, nb, 1), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((1, s), lambda i, j: (i, 0)),
+            _head_spec((lk, g, hk)),
+            _head_spec((nb, block, hk)), _head_spec((nb, 1, 1)),
+            _head_spec((nb, block, hv)), _head_spec((nb, 1, 1)),
+            _mask_spec(s),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda i, j: (i, j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        out_specs=_head_spec((lv, g, hv)),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, lv, g, hv), _F32),
+        compiler_params=_compiler_params("parallel", "parallel"),
         interpret=interpret,
-    )(q, k_packed, k_scale, v_packed, v_scale, mask)
+    )(deinterleave(q.astype(_F32), lk, 2), *_blocks(k_packed, k_scale, block),
+      *_blocks(v_packed, v_scale, block), mask[:, None, :])
+    return interleave(out, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -143,22 +222,47 @@ def quant_kv_attention_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _append_kernel(pos_ref, new_ref, packed_ref, scale_ref, blk_ref, sc_ref, *,
-                   bits: int, hd: int, block: int):
-    b = pl.program_id(0)
-    off = pos_ref[b] % block
-    lev = _unpack_block(packed_ref[0], bits, hd)              # (H, block, hd)
-    fp = lev.astype(jnp.float32) * scale_ref[0]               # * (H, 1, 1)
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, block, 1), 1)
-    fp = jnp.where(idx < off, fp, 0.0)
-    new = new_ref[0].astype(jnp.float32)                      # (H, hd)
-    fp = jnp.where(idx == off, new[:, None, :], fp)
-    q = float(2 ** (bits - 1) - 1)
-    amax = jnp.max(jnp.abs(fp), axis=(1, 2), keepdims=True)   # (H, 1, 1)
-    sc = jnp.maximum(amax, 1e-12) / q
-    levn = jnp.clip(jnp.round(fp / sc), -q, q).astype(jnp.int32)
-    blk_ref[0] = _pack_lanes(levn, bits)
-    sc_ref[0] = sc
+def _append_kernel(pos_ref, *refs, bits: int, block: int, n_prefetch: int):
+    """Shared by the dense and the paged append: the index maps already
+    picked the touched block and its scale (``n_prefetch`` scalar refs
+    precede ``pos_ref``'s companions)."""
+    new_ref, blk_ref, scale_ref, out_ref, sc_ref = refs[n_prefetch - 1:]
+    pos = pos_ref[pl.program_id(0)]
+    out_ref[0], sc_ref[0] = _requant(
+        blk_ref[0, :, 0], scale_ref[0, :, 0],
+        _new_planes(new_ref[0], LANES[bits]), pos % block, bits=bits)
+
+
+def _append_call(index_map, n_prefetch, prefetch, new, packed, scale, *,
+                 bits: int, block: int, interpret: bool):
+    """One program per slot over all H heads; ``index_map(i, *prefetch)``
+    gives the (B or P)-axis and block-axis index of the touched block in
+    the ``(·, H, nb, block, hdp)`` bytes view."""
+    b, h = new.shape[:2]
+    lanes = LANES[bits]
+    hdp = packed.shape[-1]
+    at = lambda i, *p: (index_map(i, *p)[0], 0, index_map(i, *p)[1], 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, h, lanes, 1, hdp), lambda i, *_: (i, 0, 0, 0, 0)),
+            pl.BlockSpec((1, h, 1, block, hdp), at),
+            pl.BlockSpec((1, h, 1, 1, 1), at),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, h, block, hdp), lambda i, *_: (i, 0, 0, 0)),
+            pl.BlockSpec((1, h, 1, 1), lambda i, *_: (i, 0, 0, 0)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_append_kernel, bits=bits, block=block,
+                          n_prefetch=n_prefetch),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, h, block, hdp), jnp.int8),
+                   jax.ShapeDtypeStruct((b, h, 1, 1), _F32)],
+        interpret=interpret,
+    )(*prefetch, _rows_as_planes(new, lanes), packed, scale)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "hd", "block", "interpret"))
@@ -179,30 +283,35 @@ def quant_kv_append_pallas(
     DMAs — the only cache bytes the append ever touches.  The caller places
     the block back (ops.place_block, shared with the jnp reference path).
     """
-    b, h = new.shape[:2]
-    hdp = packed.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, h, hd), lambda i, pos_ref: (i, 0, 0)),
-            pl.BlockSpec((1, h, block, hdp),
-                         lambda i, pos_ref: (i, 0, pos_ref[i] // block, 0)),
-            pl.BlockSpec((1, h, 1, 1),
-                         lambda i, pos_ref: (i, 0, pos_ref[i] // block, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, block, hdp), lambda i, pos_ref: (i, 0, 0, 0)),
-            pl.BlockSpec((1, h, 1, 1), lambda i, pos_ref: (i, 0, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_append_kernel, bits=bits, hd=hd, block=block),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, h, block, hdp), jnp.int8),
-                   jax.ShapeDtypeStruct((b, h, 1, 1), jnp.float32)],
-        interpret=interpret,
-    )(jnp.asarray(pos, jnp.int32), new, packed, scale)
+    return _append_call(lambda i, p: (i, p[i] // block), 1,
+                        (jnp.asarray(pos, jnp.int32),), new,
+                        *_blocks(packed, scale, block), bits=bits, block=block,
+                        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "hd", "block", "interpret"))
+def quant_kv_append_paged_pallas(
+    pos: jax.Array,      # (B,) int32 per-slot write positions
+    table: jax.Array,    # (B, S/block) int32 block table
+    new: jax.Array,      # (B, H, hd) float — the new token's K (or V)
+    packed: jax.Array,   # (P, H, block, hd/lanes) int8 — the pool
+    scale: jax.Array,    # (P, H, 1, 1) f32
+    *,
+    bits: int,
+    hd: int,
+    block: int,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Paged variant of the append: the scalar-prefetched (pos, table) pair
+    selects the ONE physical pool block each slot's write lands in; the
+    kernel body (the dense append's) dequantizes it, inserts the row, and
+    requantizes.  The caller scatters the emitted block + scale back into
+    the pool at the same physical ids (ops.place_paged_block)."""
+    return _append_call(
+        lambda i, p, t: (jnp.maximum(t[i, p[i] // block], 0), 0), 2,
+        (jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32)), new,
+        packed[:, :, None], scale[:, :, None], bits=bits, block=block,
+        interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -210,26 +319,40 @@ def quant_kv_append_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _paged_attn_kernel(tbl_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref,
-                       mask_ref, out_ref, kacc, ksacc, vacc, vsacc, *,
-                       k_bits: int, v_bits: int, hd: int, block: int):
-    i, b = pl.program_id(0), pl.program_id(2)
-    nb = pl.num_programs(2)
-    # gather phase: the BlockSpec index maps already DMA'd the table-mapped
-    # pool block; unmapped entries (clamped to the trash block) zero-fill so
-    # the gathered layout matches a dense cache's never-written regions.
-    mapped = tbl_ref[i, b] >= 0
-    kacc[pl.ds(b * block, block), :] = jnp.where(mapped, kp_ref[0, 0], jnp.int8(0))
-    vacc[pl.ds(b * block, block), :] = jnp.where(mapped, vp_ref[0, 0], jnp.int8(0))
-    ksacc[pl.ds(b, 1), :] = jnp.where(mapped, ks_ref[0, 0], 1e-12).reshape(1, 1)
-    vsacc[pl.ds(b, 1), :] = jnp.where(mapped, vs_ref[0, 0], 1e-12).reshape(1, 1)
+def _gather_block(mapped, blk, p_ref, s_ref, buf, col):
+    """Copy one pool block (and its scale) into the dense-layout scratch;
+    unmapped entries (clamped to the trash block) zero-fill so the gathered
+    view matches a dense cache's never-written regions."""
+    buf[blk] = jnp.where(mapped, p_ref[0, 0].astype(jnp.int32), 0)
+    col[blk] = jnp.where(mapped, s_ref[0, 0], 1e-12)
 
-    @pl.when(b == nb - 1)
+
+def _paged_attn_kernel(tbl_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref,
+                       mask_ref, out_ref, kbuf, kcol, vbuf, vcol, *,
+                       k_bits: int, v_bits: int, hd: int):
+    i, blk = pl.program_id(0), pl.program_id(2)
+    mapped = tbl_ref[i, blk] >= 0
+    _gather_block(mapped, blk, kp_ref, ks_ref, kbuf, kcol)
+    _gather_block(mapped, blk, vp_ref, vs_ref, vbuf, vcol)
+
+    @pl.when(blk == pl.num_programs(2) - 1)
     def _():
-        out_ref[0, 0] = _attn_math(q_ref[0, 0], kacc[...], ksacc[...],
-                                   vacc[...], vsacc[...], mask_ref[...],
-                                   k_bits=k_bits, v_bits=v_bits, hd=hd,
-                                   block=block)
+        _store_planes(out_ref, _attend(
+            q_ref[0, 0], kbuf[...], kcol[...], vbuf[...], vcol[...],
+            mask_ref[0], k_bits=k_bits, v_bits=v_bits, hd=hd))
+
+
+def _paged_scratch(nb, block, hk, hv):
+    return [pltpu.VMEM((nb, block, hk), jnp.int32), pltpu.VMEM((nb, 1, 1), _F32),
+            pltpu.VMEM((nb, block, hv), jnp.int32), pltpu.VMEM((nb, 1, 1), _F32)]
+
+
+def _pool_spec(shape):
+    """Pool block ``(1, 1, *shape)`` that the slot's table maps at the
+    grid's block index (unmapped entries clamp to the trash block)."""
+    return pl.BlockSpec(
+        (1, 1, *shape),
+        lambda i, j, blk, *pf: (jnp.maximum(pf[-1][i, blk], 0), j, 0, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("k_bits", "v_bits", "hd", "block",
@@ -254,44 +377,34 @@ def quant_kv_attention_paged_pallas(
     The scalar-prefetched table row drives the K/V BlockSpec index maps, so
     each (slot, head) program DMAs exactly the pool blocks its table maps —
     never the whole pool — then runs the SAME attention math as the dense
-    kernel on the gathered (S, ·) scratch.
+    kernel on the gathered (nb, block, ·) scratch.
     """
     b, n_kv, g, _ = q.shape
     nb = table.shape[1]
-    s = nb * block
+    lk, lv = LANES[k_bits], LANES[v_bits]
     hk, hv = k_packed.shape[-1], v_packed.shape[-1]
-    phys = lambda i, j, blk, tbl: jnp.maximum(tbl[i, blk], 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, n_kv, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, blk, tbl: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hk),
-                         lambda i, j, blk, tbl: (phys(i, j, blk, tbl), j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda i, j, blk, tbl: (phys(i, j, blk, tbl), j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hv),
-                         lambda i, j, blk, tbl: (phys(i, j, blk, tbl), j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda i, j, blk, tbl: (phys(i, j, blk, tbl), j, 0, 0)),
-            pl.BlockSpec((1, s), lambda i, j, blk, tbl: (i, 0)),
+            _head_spec((lk, g, hk)),
+            _pool_spec((block, hk)), _pool_spec((1, 1)),
+            _pool_spec((block, hv)), _pool_spec((1, 1)),
+            _mask_spec(nb * block),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda i, j, blk, tbl: (i, j, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((s, hk), jnp.int8), pltpu.VMEM((nb, 1), jnp.float32),
-            pltpu.VMEM((s, hv), jnp.int8), pltpu.VMEM((nb, 1), jnp.float32),
-        ],
+        out_specs=_head_spec((lv, g, hv)),
+        scratch_shapes=_paged_scratch(nb, block, hk, hv),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, k_bits=k_bits, v_bits=v_bits,
-                          hd=hd, block=block),
+                          hd=hd),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, lv, g, hv), _F32),
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(jnp.asarray(table, jnp.int32), q, k_packed, k_scale, v_packed, v_scale,
-      mask)
+    )(jnp.asarray(table, jnp.int32), deinterleave(q.astype(_F32), lk, 2),
+      k_packed, k_scale, v_packed, v_scale, mask[:, None, :])
+    return interleave(out, 2)
 
 
 # ---------------------------------------------------------------------------
@@ -299,58 +412,78 @@ def quant_kv_attention_paged_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _requant_row(p_full, s_full, new, off, bidx, *, bits: int, hd: int,
-                 block: int):
-    """Shared fused-step requant core: insert ``new`` into the touched block.
-
-    ``p_full``: (S, hd/lanes) packed; ``s_full``: (nb, 1) scales; ``new``:
-    (hd,) fp; ``off``/``bidx``: scalars.  Returns the requantized packed
-    block (block, hd/lanes), its (1, 1) scale, and the *updated* full
-    (S, ·)/(nb, 1) views — the exact bytes the sequential append + scatter
-    would have produced, built in VMEM so attention reads them with zero
-    extra HBM traffic.  The math is `_append_kernel`'s, specialized to the
-    one head this program owns.
-    """
-    blk = jax.lax.dynamic_slice_in_dim(p_full, bidx * block, block, axis=0)
-    sc = jax.lax.dynamic_slice_in_dim(s_full, bidx, 1, axis=0)    # (1, 1)
-    lev = _unpack_block(blk[None], bits, hd)                      # (1, block, hd)
-    fp = lev.astype(jnp.float32) * sc[None]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, block, 1), 1)
-    fp = jnp.where(idx < off, fp, 0.0)
-    fp = jnp.where(idx == off, new[None, None, :].astype(jnp.float32), fp)
-    q = float(2 ** (bits - 1) - 1)
-    amax = jnp.max(jnp.abs(fp), axis=(1, 2), keepdims=True)       # (1, 1, 1)
-    scn = jnp.maximum(amax, 1e-12) / q
-    levn = jnp.clip(jnp.round(fp / scn), -q, q).astype(jnp.int32)
-    pb = _pack_lanes(levn, bits)[0]                               # (block, hdp)
-    scn = scn[0]                                                  # (1, 1)
-    p_upd = jax.lax.dynamic_update_slice_in_dim(p_full, pb, bidx * block,
-                                                axis=0)
-    s_upd = jax.lax.dynamic_update_slice_in_dim(s_full, scn, bidx, axis=0)
-    return pb, scn, p_upd, s_upd
+def _step_side(new, p_ref, s_ref, t_ref, ts_ref, blk_ref, sc_ref, buf, bidx,
+               off, *, bits: int):
+    """One side (K or V) of the dense fused step: requantize the touched
+    block, emit it, and build the post-append view attention reads — the
+    slot's bytes with the new block spliced in (exactly what append +
+    ``place_block`` would leave in HBM) and the updated scales."""
+    blk, sc = _requant(t_ref[0, 0, 0], ts_ref[0, 0, 0], new, off, bits=bits)
+    blk_ref[0, 0] = blk
+    sc_ref[0, 0] = sc
+    buf[...] = p_ref[0, 0].astype(jnp.int32)
+    buf[bidx] = blk.astype(jnp.int32)
+    return buf[...], _set_block(s_ref[0, 0], bidx, sc)
 
 
 def _fused_step_kernel(pos_ref, q_ref, kn_ref, vn_ref, kp_ref, ks_ref, vp_ref,
-                       vs_ref, mask_ref, out_ref, kblk_ref, ksc_ref, vblk_ref,
-                       vsc_ref, *, k_bits: int, v_bits: int, hd: int,
-                       block: int):
-    i = pl.program_id(0)
-    pos = pos_ref[i]
-    bidx = pos // block
-    off = pos % block
-    kb, ksn, kp_upd, ks_upd = _requant_row(kp_ref[0, 0], ks_ref[0, 0],
-                                           kn_ref[0, 0], off, bidx,
-                                           bits=k_bits, hd=hd, block=block)
-    vb, vsn, vp_upd, vs_upd = _requant_row(vp_ref[0, 0], vs_ref[0, 0],
-                                           vn_ref[0, 0], off, bidx,
-                                           bits=v_bits, hd=hd, block=block)
-    kblk_ref[0, 0] = kb
-    ksc_ref[0, 0] = ksn
-    vblk_ref[0, 0] = vb
-    vsc_ref[0, 0] = vsn
-    out_ref[0, 0] = _attn_math(q_ref[0, 0], kp_upd, ks_upd, vp_upd, vs_upd,
-                               mask_ref[...], k_bits=k_bits, v_bits=v_bits,
-                               hd=hd, block=block)
+                       vs_ref, kt_ref, kts_ref, vt_ref, vts_ref, mask_ref,
+                       out_ref, kblk_ref, ksc_ref, vblk_ref, vsc_ref, kbuf,
+                       vbuf, *, k_bits: int, v_bits: int, hd: int, block: int):
+    pos = pos_ref[pl.program_id(0)]
+    bidx, off = pos // block, pos % block
+    kbytes, kcol = _step_side(
+        _new_planes(kn_ref[0, 0], LANES[k_bits]), kp_ref, ks_ref, kt_ref,
+        kts_ref, kblk_ref, ksc_ref, kbuf, bidx, off, bits=k_bits)
+    vbytes, vcol = _step_side(
+        _new_planes(vn_ref[0, 0], LANES[v_bits]), vp_ref, vs_ref, vt_ref,
+        vts_ref, vblk_ref, vsc_ref, vbuf, bidx, off, bits=v_bits)
+    _store_planes(out_ref, _attend(q_ref[0, 0], kbytes, kcol, vbytes, vcol,
+                                   mask_ref[0], k_bits=k_bits, v_bits=v_bits,
+                                   hd=hd))
+
+
+def _dense_step_specs(nb, block, hk, hv):
+    """Whole-slot bytes + scales, then the touched block + scale, per side
+    (the same arrays enter twice: the touched-block specs DMA the append
+    target on their own)."""
+    touched = lambda shape: pl.BlockSpec(
+        (1, 1, 1, *shape), lambda i, j, p: (i, j, p[i] // block, 0, 0))
+    return [_head_spec((nb, block, hk)), _head_spec((nb, 1, 1)),
+            _head_spec((nb, block, hv)), _head_spec((nb, 1, 1)),
+            touched((block, hk)), touched((1, 1)),
+            touched((block, hv)), touched((1, 1))]
+
+
+def _dense_step_args(k_packed, k_scale, v_packed, v_scale, block):
+    kv = (*_blocks(k_packed, k_scale, block), *_blocks(v_packed, v_scale, block))
+    return kv + kv
+
+
+def _step_outputs(b, n_kv, g, block, lv, hk, hv):
+    return dict(
+        out_specs=[_head_spec((lv, g, hv)), _head_spec((block, hk)),
+                   _head_spec((1, 1)), _head_spec((block, hv)),
+                   _head_spec((1, 1))],
+        out_shape=[jax.ShapeDtypeStruct((b, n_kv, lv, g, hv), _F32),
+                   jax.ShapeDtypeStruct((b, n_kv, block, hk), jnp.int8),
+                   jax.ShapeDtypeStruct((b, n_kv, 1, 1), _F32),
+                   jax.ShapeDtypeStruct((b, n_kv, block, hv), jnp.int8),
+                   jax.ShapeDtypeStruct((b, n_kv, 1, 1), _F32)])
+
+
+def _step_call(kernel, grid, n_prefetch, in_specs, scratch, args, *, b, n_kv,
+               g, block, lv, hk, hv, interpret):
+    outs = _step_outputs(b, n_kv, g, block, lv, hk, hv)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch, grid=grid, in_specs=in_specs,
+        out_specs=outs["out_specs"], scratch_shapes=scratch)
+    semantics = ("parallel", "parallel") + ("arbitrary",) * (len(grid) - 2)
+    o, kb, ks, vb, vs = pl.pallas_call(
+        kernel, grid_spec=grid_spec, out_shape=outs["out_shape"],
+        compiler_params=_compiler_params(*semantics), interpret=interpret,
+    )(*args)
+    return interleave(o, 2), kb, ks, vb, vs
 
 
 @functools.partial(jax.jit, static_argnames=("k_bits", "v_bits", "hd", "block",
@@ -374,116 +507,73 @@ def quant_kv_decode_step_pallas(
 ):
     """ONE kernel per (slot, head): dequant + append/requant + attend.
 
-    The packed cache bytes cross HBM->VMEM exactly once per decode step;
-    the post-append view attention needs is built in VMEM by splicing the
-    requantized block into the just-DMA'd buffer.  Emits the attention
-    output plus the touched block + scale per side — the caller scatters
-    them back with the same ``ops.place_block`` the sequential path uses,
-    so the updated cache is bit-identical to append-then-attend.
+    The packed cache bytes cross HBM->VMEM once per decode step (plus the
+    touched block, read again through its own BlockSpec); the post-append
+    view attention needs is built in VMEM by splicing the requantized block
+    into the slot's bytes.  Emits the attention output plus the touched
+    block + scale per side — the caller scatters them back with the same
+    ``ops.place_block`` the sequential path uses, so the updated cache is
+    bit-identical to append-then-attend.
     """
     b, n_kv, g, _ = q.shape
-    s = k_packed.shape[2]
-    nb = s // block
+    nb = k_packed.shape[2] // block
+    lk, lv = LANES[k_bits], LANES[v_bits]
     hk, hv = k_packed.shape[-1], v_packed.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, hd), lambda i, j, pos_r: (i, j, 0)),
-            pl.BlockSpec((1, 1, hd), lambda i, j, pos_r: (i, j, 0)),
-            pl.BlockSpec((1, 1, s, hk), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, nb, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, s, hv), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, nb, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, s), lambda i, j, pos_r: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hk), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hv), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
+    return _step_call(
         functools.partial(_fused_step_kernel, k_bits=k_bits, v_bits=v_bits,
                           hd=hd, block=block),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, block, hk), jnp.int8),
-            jax.ShapeDtypeStruct((b, n_kv, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, block, hv), jnp.int8),
-            jax.ShapeDtypeStruct((b, n_kv, 1, 1), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(jnp.asarray(pos, jnp.int32), q, k_new, v_new, k_packed, k_scale,
-      v_packed, v_scale, mask)
+        (b, n_kv), 1,
+        [_head_spec((lk, g, hk)), _head_spec((lk, 1, hk)),
+         _head_spec((lv, 1, hv)), *_dense_step_specs(nb, block, hk, hv),
+         _mask_spec(nb * block)],
+        [pltpu.VMEM((nb, block, hk), jnp.int32),
+         pltpu.VMEM((nb, block, hv), jnp.int32)],
+        (jnp.asarray(pos, jnp.int32), deinterleave(q.astype(_F32), lk, 2),
+         _rows_as_planes(k_new, lk), _rows_as_planes(v_new, lv),
+         *_dense_step_args(k_packed, k_scale, v_packed, v_scale, block),
+         mask[:, None, :]),
+        b=b, n_kv=n_kv, g=g, block=block, lv=lv, hk=hk, hv=hv,
+        interpret=interpret)
 
 
 def _fused_step_paged_kernel(pos_ref, tbl_ref, q_ref, kn_ref, vn_ref, kp_ref,
-                             ks_ref, vp_ref, vs_ref, ktch_ref, kts_ref,
-                             vtch_ref, vts_ref, mask_ref, out_ref, kblk_ref,
-                             ksc_ref, vblk_ref, vsc_ref, kacc, ksacc, vacc,
-                             vsacc, *, k_bits: int, v_bits: int, hd: int,
-                             block: int):
-    i, b = pl.program_id(0), pl.program_id(2)
-    nb = pl.num_programs(2)
-    # gather phase — identical to _paged_attn_kernel: table-mapped pool
-    # blocks land in the dense-layout scratch, unmapped entries zero-fill.
-    mapped = tbl_ref[i, b] >= 0
-    kacc[pl.ds(b * block, block), :] = jnp.where(mapped, kp_ref[0, 0], jnp.int8(0))
-    vacc[pl.ds(b * block, block), :] = jnp.where(mapped, vp_ref[0, 0], jnp.int8(0))
-    ksacc[pl.ds(b, 1), :] = jnp.where(mapped, ks_ref[0, 0], 1e-12).reshape(1, 1)
-    vsacc[pl.ds(b, 1), :] = jnp.where(mapped, vs_ref[0, 0], 1e-12).reshape(1, 1)
+                             ks_ref, vp_ref, vs_ref, kt_ref, kts_ref, vt_ref,
+                             vts_ref, mask_ref, out_ref, kblk_ref, ksc_ref,
+                             vblk_ref, vsc_ref, kbuf, kcol, vbuf, vcol, *,
+                             k_bits: int, v_bits: int, hd: int, block: int):
+    i, blk = pl.program_id(0), pl.program_id(2)
+    mapped = tbl_ref[i, blk] >= 0
+    _gather_block(mapped, blk, kp_ref, ks_ref, kbuf, kcol)
+    _gather_block(mapped, blk, vp_ref, vs_ref, vbuf, vcol)
 
-    @pl.when(b == nb - 1)
+    @pl.when(blk == pl.num_programs(2) - 1)
     def _():
         pos = pos_ref[i]
-        bidx = pos // block
-        off = pos % block
-        # The touched *physical* block was DMA'd separately (ktch/vtch), so
-        # idle slots requantize the real trash-block contents — exactly what
-        # the sequential paged append emits.  The attention view substitutes
-        # the update only where the slot's table actually maps the block
-        # (trash writes must stay invisible, as they are in the sequential
-        # gather over the post-scatter pool).
+        bidx, off = pos // block, pos % block
+        # The touched *physical* block was DMA'd separately, so idle slots
+        # requantize the real trash-block contents — exactly what the
+        # sequential paged append emits.  The attention view takes the
+        # update only where the slot's table maps the block (trash writes
+        # stay invisible, as in the sequential gather over the new pool).
         mapped_t = tbl_ref[i, bidx] >= 0
 
-        def side(tch, tsc, new, bits, blk_out, sc_out, acc, sacc):
-            lev = _unpack_block(tch[None], bits, hd)              # (1, block, hd)
-            fp = lev.astype(jnp.float32) * tsc[None]
-            idx = jax.lax.broadcasted_iota(jnp.int32, (1, block, 1), 1)
-            fp = jnp.where(idx < off, fp, 0.0)
-            fp = jnp.where(idx == off, new[None, None, :].astype(jnp.float32),
-                           fp)
-            qm = float(2 ** (bits - 1) - 1)
-            amax = jnp.max(jnp.abs(fp), axis=(1, 2), keepdims=True)
-            scn = jnp.maximum(amax, 1e-12) / qm
-            levn = jnp.clip(jnp.round(fp / scn), -qm, qm).astype(jnp.int32)
-            pb = _pack_lanes(levn, bits)[0]                       # (block, hdp)
-            scn = scn[0]                                          # (1, 1)
-            blk_out[0, 0] = pb
-            sc_out[0, 0] = scn
-            full = acc[...]
-            sfull = sacc[...]
-            p_upd = jax.lax.dynamic_update_slice_in_dim(full, pb, bidx * block,
-                                                        axis=0)
-            s_upd = jax.lax.dynamic_update_slice_in_dim(sfull, scn, bidx,
-                                                        axis=0)
-            return (jnp.where(mapped_t, p_upd, full),
-                    jnp.where(mapped_t, s_upd, sfull))
+        def side(new, t_ref, ts_ref, blk_ref, sc_ref, buf, col, bits):
+            nblk, sc = _requant(t_ref[0, 0], ts_ref[0, 0], new, off, bits=bits)
+            blk_ref[0, 0] = nblk
+            sc_ref[0, 0] = sc
 
-        kf, ksf = side(ktch_ref[0, 0], kts_ref[0, 0], kn_ref[0, 0], k_bits,
-                       kblk_ref, ksc_ref, kacc, ksacc)
-        vf, vsf = side(vtch_ref[0, 0], vts_ref[0, 0], vn_ref[0, 0], v_bits,
-                       vblk_ref, vsc_ref, vacc, vsacc)
-        out_ref[0, 0] = _attn_math(q_ref[0, 0], kf, ksf, vf, vsf,
-                                   mask_ref[...], k_bits=k_bits,
-                                   v_bits=v_bits, hd=hd, block=block)
+            @pl.when(mapped_t)
+            def _():
+                buf[bidx] = nblk.astype(jnp.int32)
+                col[bidx] = sc
+
+        side(_new_planes(kn_ref[0, 0], LANES[k_bits]), kt_ref, kts_ref,
+             kblk_ref, ksc_ref, kbuf, kcol, k_bits)
+        side(_new_planes(vn_ref[0, 0], LANES[v_bits]), vt_ref, vts_ref,
+             vblk_ref, vsc_ref, vbuf, vcol, v_bits)
+        _store_planes(out_ref, _attend(
+            q_ref[0, 0], kbuf[...], kcol[...], vbuf[...], vcol[...],
+            mask_ref[0], k_bits=k_bits, v_bits=v_bits, hd=hd))
 
 
 @functools.partial(jax.jit, static_argnames=("k_bits", "v_bits", "hd", "block",
@@ -522,117 +612,82 @@ def quant_kv_decode_step_paged_pallas(
     """
     b, n_kv, g, _ = q.shape
     nb = table.shape[1]
-    s = nb * block
+    lk, lv = LANES[k_bits], LANES[v_bits]
     hk, hv = k_packed.shape[-1], v_packed.shape[-1]
-    phys = lambda i, blk, tbl: jnp.maximum(tbl[i, blk], 0)
-    physt = lambda i, pos_r, tbl: jnp.maximum(tbl[i, pos_r[i] // block], 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, n_kv, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, blk, p_, t_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, hd), lambda i, j, blk, p_, t_: (i, j, 0)),
-            pl.BlockSpec((1, 1, hd), lambda i, j, blk, p_, t_: (i, j, 0)),
-            pl.BlockSpec((1, 1, block, hk),
-                         lambda i, j, blk, p_, t_: (phys(i, blk, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda i, j, blk, p_, t_: (phys(i, blk, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hv),
-                         lambda i, j, blk, p_, t_: (phys(i, blk, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda i, j, blk, p_, t_: (phys(i, blk, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hk),
-                         lambda i, j, blk, p_, t_: (physt(i, p_, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda i, j, blk, p_, t_: (physt(i, p_, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hv),
-                         lambda i, j, blk, p_, t_: (physt(i, p_, t_), j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1),
-                         lambda i, j, blk, p_, t_: (physt(i, p_, t_), j, 0, 0)),
-            pl.BlockSpec((1, s), lambda i, j, blk, p_, t_: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, blk, p_, t_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hk),
-                         lambda i, j, blk, p_, t_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, blk, p_, t_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hv),
-                         lambda i, j, blk, p_, t_: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, blk, p_, t_: (i, j, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((s, hk), jnp.int8), pltpu.VMEM((nb, 1), jnp.float32),
-            pltpu.VMEM((s, hv), jnp.int8), pltpu.VMEM((nb, 1), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+    touched = lambda shape: pl.BlockSpec(
+        (1, 1, *shape),
+        lambda i, j, blk, p, t: (jnp.maximum(t[i, p[i] // block], 0), j, 0, 0))
+    pool = (k_packed, k_scale, v_packed, v_scale)
+    return _step_call(
         functools.partial(_fused_step_paged_kernel, k_bits=k_bits,
                           v_bits=v_bits, hd=hd, block=block),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, block, hk), jnp.int8),
-            jax.ShapeDtypeStruct((b, n_kv, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, block, hv), jnp.int8),
-            jax.ShapeDtypeStruct((b, n_kv, 1, 1), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32), q, k_new,
-      v_new, k_packed, k_scale, v_packed, v_scale,
-      # the pool buffers again: the touched-block specs (physt index map)
-      # DMA the append target separately from the gather stream
-      k_packed, k_scale, v_packed, v_scale, mask)
+        (b, n_kv, nb), 2,
+        [_head_spec((lk, g, hk)), _head_spec((lk, 1, hk)),
+         _head_spec((lv, 1, hv)),
+         _pool_spec((block, hk)), _pool_spec((1, 1)),
+         _pool_spec((block, hv)), _pool_spec((1, 1)),
+         touched((block, hk)), touched((1, 1)),
+         touched((block, hv)), touched((1, 1)),
+         _mask_spec(nb * block)],
+        _paged_scratch(nb, block, hk, hv),
+        # the pool buffers enter twice: the touched-block specs DMA the
+        # append target separately from the gather stream
+        (jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32),
+         deinterleave(q.astype(_F32), lk, 2), _rows_as_planes(k_new, lk),
+         _rows_as_planes(v_new, lv), *pool, *pool, mask[:, None, :]),
+        b=b, n_kv=n_kv, g=g, block=block, lv=lv, hk=hk, hv=hv,
+        interpret=interpret)
 
 
-def _rope_rows(x, cos, sin, hd: int):
-    """Rotate (rows, hd) by (1, hd/2) cos/sin — `models.layers.apply_rope`'s
-    math specialized to one position (the decode token)."""
-    x1 = x[:, :hd // 2]
-    x2 = x[:, hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+def _split_lanes(row: jax.Array, lanes: int) -> list[jax.Array]:
+    """(1, hd) row -> ``lanes`` (1, hd/lanes) planes, in-kernel.
+
+    One exact one-hot dot per plane (each output is a single input value
+    times 1.0 at f32 precision), since a strided lane gather has no cheap
+    vector form.
+    """
+    if lanes == 1:
+        return [row]
+    hd = row.shape[-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, (hd, hd // lanes), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (hd, hd // lanes), 1)
+    return [_dot(row, (r == c * lanes + lane).astype(_F32), ((1,), (0,)))
+            for lane in range(lanes)]
 
 
 def _fused_step_proj_kernel(pos_ref, x_ref, wq_ref, wqs_ref, wk_ref, wks_ref,
                             wv_ref, wvs_ref, cos_ref, sin_ref, kp_ref, ks_ref,
-                            vp_ref, vs_ref, mask_ref, out_ref, kblk_ref,
-                            ksc_ref, vblk_ref, vsc_ref, *, w_bits: int,
-                            k_bits: int, v_bits: int, d: int, g: int, hd: int,
+                            vp_ref, vs_ref, kt_ref, kts_ref, vt_ref, vts_ref,
+                            mask_ref, out_ref, kblk_ref, ksc_ref, vblk_ref,
+                            vsc_ref, qbuf, kbuf, vbuf, *, w_bits: int,
+                            k_bits: int, v_bits: int, g: int, hd: int,
                             block: int):
-    i = pl.program_id(0)
-    pos = pos_ref[i]
-    bidx = pos // block
-    off = pos % block
-    x = x_ref[...].astype(jnp.float32)                            # (1, d)
+    pos = pos_ref[pl.program_id(0)]
+    bidx, off = pos // block, pos % block
+    x = x_ref[0]                                              # (lanes_w, 1, dp)
+    cos, sin = cos_ref[0], sin_ref[0]                         # (1, hd)
 
-    def proj(w_ref, ws_ref):
-        # quant_gemv's inner step at one K block: integer-level dot first,
-        # per-output-channel scale after the accumulation finishes.
-        lev = _unpack_block(w_ref[...], w_bits, d)                # (rows, d)
-        acc = jax.lax.dot_general(x, lev.astype(jnp.float32),
-                                  (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return acc * ws_ref[...]                                  # (1, rows)
+    def proj(w_ref, ws_ref, h, rope: bool):
+        # quant_gemv's order: integer-level plane dots, then the scale
+        row = plane_dot(x, w_ref[h], w_bits) * ws_ref[h]      # (1, hd)
+        if rope:  # [x1, x2] -> [x1 cos - x2 sin, x2 cos + x1 sin]
+            row = row * cos + pltpu.roll(row, hd // 2, 1) * sin
+        return row
 
-    cos = cos_ref[...]                                            # (1, hd/2)
-    sin = sin_ref[...]
-    qrows = _rope_rows(proj(wq_ref, wqs_ref).reshape(g, hd), cos, sin, hd)
-    krow = _rope_rows(proj(wk_ref, wks_ref), cos, sin, hd)        # (1, hd)
-    vrow = proj(wv_ref, wvs_ref)                                  # (1, hd)
-    kb, ksn, kp_upd, ks_upd = _requant_row(kp_ref[0, 0], ks_ref[0, 0],
-                                           krow[0], off, bidx, bits=k_bits,
-                                           hd=hd, block=block)
-    vb, vsn, vp_upd, vs_upd = _requant_row(vp_ref[0, 0], vs_ref[0, 0],
-                                           vrow[0], off, bidx, bits=v_bits,
-                                           hd=hd, block=block)
-    kblk_ref[0, 0] = kb
-    ksc_ref[0, 0] = ksn
-    vblk_ref[0, 0] = vb
-    vsc_ref[0, 0] = vsn
-    out_ref[0, 0] = _attn_math(qrows, kp_upd, ks_upd, vp_upd, vs_upd,
-                               mask_ref[...], k_bits=k_bits, v_bits=v_bits,
-                               hd=hd, block=block)
+    lk, lv = LANES[k_bits], LANES[v_bits]
+    for h in range(g):
+        planes = _split_lanes(proj(wq_ref, wqs_ref, h, True), lk)
+        for lane, plane in enumerate(planes):
+            qbuf[lane, h:h + 1, :] = plane
+    kbytes, kcol = _step_side(
+        _split_lanes(proj(wk_ref, wks_ref, 0, True), lk), kp_ref, ks_ref,
+        kt_ref, kts_ref, kblk_ref, ksc_ref, kbuf, bidx, off, bits=k_bits)
+    vbytes, vcol = _step_side(
+        _split_lanes(proj(wv_ref, wvs_ref, 0, False), lv), vp_ref, vs_ref,
+        vt_ref, vts_ref, vblk_ref, vsc_ref, vbuf, bidx, off, bits=v_bits)
+    _store_planes(out_ref, _attend(qbuf[...], kbytes, kcol, vbytes, vcol,
+                                   mask_ref[0], k_bits=k_bits, v_bits=v_bits,
+                                   hd=hd))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -661,119 +716,53 @@ def quant_kv_decode_step_proj_pallas(
     """Fused step with the Q/K/V projection pulled into the same dispatch.
 
     Each (slot, kv-head) program DMAs only its slice of the fused ``wqkv``
-    buffer — the query group's ``g*hd`` rows plus one ``hd`` K row-block and
-    one V row-block, selected by BlockSpec row-block index — projects with
-    the gemv integer-dot + scale-after order, applies rope, and falls into
-    the same requant + attend body as the plain fused step.  Geometry gate
-    (ops.py): fused ``wqkv`` leaf, default rope, no qk-norm, single gemv
-    K-step (d <= 512).
+    buffer, viewed as ``(N/hd, hd, ·)`` head blocks — the query group's
+    ``g`` heads plus one K head and one V head — projects with the gemv
+    plane-dot + scale-after order, applies rope, and falls into the same
+    requant + attend body as the plain fused step.  Geometry gate (ops.py):
+    fused ``wqkv`` leaf, default rope, no qk-norm, single gemv K-step
+    (d <= 512).
     """
     b, d = x.shape
     n_kv = k_packed.shape[1]
     g = n_heads // n_kv
-    s = k_packed.shape[2]
-    nb = s // block
+    nb = k_packed.shape[2] // block
+    lw, lk, lv = LANES[w_bits], LANES[k_bits], LANES[v_bits]
     hk, hv = k_packed.shape[-1], v_packed.shape[-1]
     dp = w_packed.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, pos_r: (i, 0)),
-            # wqkv rows: q group j = row-block j of g*hd rows; K head j and
-            # V head j = hd-row blocks at offsets n_heads + j / n_heads +
-            # n_kv + j (in hd-row units).
-            pl.BlockSpec((g * hd, dp), lambda i, j, pos_r: (j, 0)),
-            pl.BlockSpec((1, g * hd), lambda i, j, pos_r: (0, j)),
-            pl.BlockSpec((hd, dp), lambda i, j, pos_r: (n_heads + j, 0)),
-            pl.BlockSpec((1, hd), lambda i, j, pos_r: (0, n_heads + j)),
-            pl.BlockSpec((hd, dp), lambda i, j, pos_r: (n_heads + n_kv + j, 0)),
-            pl.BlockSpec((1, hd), lambda i, j, pos_r: (0, n_heads + n_kv + j)),
-            pl.BlockSpec((1, hd // 2), lambda i, j, pos_r: (i, 0)),
-            pl.BlockSpec((1, hd // 2), lambda i, j, pos_r: (i, 0)),
-            pl.BlockSpec((1, 1, s, hk), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, nb, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, s, hv), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, nb, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, s), lambda i, j, pos_r: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hk), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, block, hv), lambda i, j, pos_r: (i, j, 0, 0)),
-            pl.BlockSpec((1, 1, 1, 1), lambda i, j, pos_r: (i, j, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
+    n_rows = w_packed.shape[0] // hd
+    # wqkv head blocks: q group j = block j of g heads; K head j and V head
+    # j = single-head blocks n_heads + j and n_heads + n_kv + j
+    heads = lambda n, first, shape: pl.BlockSpec(
+        (n, *shape), lambda i, j, p: (first + j, 0, 0))
+    row = lambda shape: pl.BlockSpec((1, *shape), lambda i, j, p: (i, 0, 0))
+    # full-width rope rows: x*cos_f + roll(x, hd/2)*sin_f is apply_rope's
+    # [x1 cos - x2 sin, x2 cos + x1 sin]
+    cos_f = jnp.concatenate([cos, cos], axis=-1)[:, None, :]
+    sin_f = jnp.concatenate([-sin, sin], axis=-1)[:, None, :]
+    w3 = w_packed.reshape(n_rows, hd, dp)
+    s3 = w_scale.reshape(n_rows, 1, hd)
+    return _step_call(
         functools.partial(_fused_step_proj_kernel, w_bits=w_bits,
-                          k_bits=k_bits, v_bits=v_bits, d=d, g=g, hd=hd,
+                          k_bits=k_bits, v_bits=v_bits, g=g, hd=hd,
                           block=block),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, n_kv, g, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, block, hk), jnp.int8),
-            jax.ShapeDtypeStruct((b, n_kv, 1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((b, n_kv, block, hv), jnp.int8),
-            jax.ShapeDtypeStruct((b, n_kv, 1, 1), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-        # the fused wqkv buffer + scale enter three times — the q-group, K-head,
-        # and V-head specs each DMA their own row-block slice
-    )(jnp.asarray(pos, jnp.int32), x, w_packed, w_scale, w_packed, w_scale,
-      w_packed, w_scale, cos, sin, k_packed, k_scale, v_packed, v_scale, mask)
-
-
-def _paged_append_kernel(pos_ref, tbl_ref, new_ref, packed_ref, scale_ref,
-                         blk_ref, sc_ref, *, bits: int, hd: int, block: int):
-    del tbl_ref  # consumed by the index maps; requant math is table-agnostic
-    _append_kernel(pos_ref, new_ref, packed_ref, scale_ref, blk_ref, sc_ref,
-                   bits=bits, hd=hd, block=block)
-
-
-@functools.partial(jax.jit, static_argnames=("bits", "hd", "block", "interpret"))
-def quant_kv_append_paged_pallas(
-    pos: jax.Array,      # (B,) int32 per-slot write positions
-    table: jax.Array,    # (B, S/block) int32 block table
-    new: jax.Array,      # (B, H, hd) float — the new token's K (or V)
-    packed: jax.Array,   # (P, H, block, hd/lanes) int8 — the pool
-    scale: jax.Array,    # (P, H, 1, 1) f32
-    *,
-    bits: int,
-    hd: int,
-    block: int,
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Paged variant of the append: the scalar-prefetched (pos, table) pair
-    selects the ONE physical pool block each slot's write lands in; the
-    kernel body (shared with the dense append) dequantizes it, inserts the
-    row, and requantizes.  The caller scatters the emitted block + scale
-    back into the pool at the same physical ids (ops.place_paged_block)."""
-    b, h = new.shape[:2]
-    hdp = packed.shape[-1]
-    phys = lambda i, pos_r, tbl_r: jnp.maximum(tbl_r[i, pos_r[i] // block], 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, h, hd), lambda i, pos_r, tbl_r: (i, 0, 0)),
-            pl.BlockSpec((1, h, block, hdp),
-                         lambda i, pos_r, tbl_r: (phys(i, pos_r, tbl_r), 0, 0, 0)),
-            pl.BlockSpec((1, h, 1, 1),
-                         lambda i, pos_r, tbl_r: (phys(i, pos_r, tbl_r), 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, block, hdp), lambda i, pos_r, tbl_r: (i, 0, 0, 0)),
-            pl.BlockSpec((1, h, 1, 1), lambda i, pos_r, tbl_r: (i, 0, 0, 0)),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_paged_append_kernel, bits=bits, hd=hd, block=block),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, h, block, hdp), jnp.int8),
-                   jax.ShapeDtypeStruct((b, h, 1, 1), jnp.float32)],
-        interpret=interpret,
-    )(jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32), new, packed,
-      scale)
+        (b, n_kv), 1,
+        [pl.BlockSpec((1, lw, 1, dp), lambda i, j, p: (i, 0, 0, 0)),
+         heads(g, 0, (hd, dp)), heads(g, 0, (1, hd)),
+         heads(1, n_heads, (hd, dp)), heads(1, n_heads, (1, hd)),
+         heads(1, n_heads + n_kv, (hd, dp)), heads(1, n_heads + n_kv, (1, hd)),
+         row((1, hd)), row((1, hd)),
+         *_dense_step_specs(nb, block, hk, hv),
+         _mask_spec(nb * block)],
+        [pltpu.VMEM((lk, g, hk), _F32),
+         pltpu.VMEM((nb, block, hk), jnp.int32),
+         pltpu.VMEM((nb, block, hv), jnp.int32)],
+        # the fused wqkv buffer + scale enter three times — the q-group,
+        # K-head and V-head specs each DMA their own head blocks
+        (jnp.asarray(pos, jnp.int32),
+         deinterleave(x.astype(_F32)[:, None, :], lw, 1),
+         w3, s3, w3, s3, w3, s3, cos_f, sin_f,
+         *_dense_step_args(k_packed, k_scale, v_packed, v_scale, block),
+         mask[:, None, :]),
+        b=b, n_kv=n_kv, g=g, block=block, lv=lv, hk=hk, hv=hv,
+        interpret=interpret)

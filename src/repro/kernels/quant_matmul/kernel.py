@@ -9,8 +9,9 @@ stands in for the ASIC's cycle savings (DESIGN.md §2).
 
 Blocking: grid (M/bm, N/bn, K/bk), K innermost ("arbitrary") so the f32
 output block is revisited and accumulated in place in VMEM.  bm = bn = 128
-aligns the MXU; bk is chosen so x-block + unpacked w-block + out-block fit
-VMEM comfortably (default 512 -> ~0.8 MB f32 working set per step).
+aligns the MXU.  Weight bytes are read as lane planes (``kernels/lanes.py``,
+shared with the GEMV): x arrives de-interleaved, and each step takes one
+dot per plane, so no unpacked (bn, bk) block is ever built.
 """
 from __future__ import annotations
 
@@ -22,44 +23,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import LANES
-
-# jax<=0.4.x names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
-def _unpack_block(packed: jax.Array, bits: int, bk: int) -> jax.Array:
-    """int8 (..., bk/lanes) -> int32 levels (..., bk), sign-extended.
-
-    The Pallas-safe twin of ``core/packing.unpack`` (same lane layout, no
-    trailing-slice/pad handling) shared by the quant_matmul / quant_gemv /
-    quant_kv kernel bodies; the cross-impl parity tests pin it bit-exact
-    against the packing module.
-    """
-    lanes = LANES[bits]
-    if lanes == 1:
-        return packed.astype(jnp.int32)
-    u = packed.astype(jnp.uint8).astype(jnp.int32)
-    mask = (1 << bits) - 1
-    sign = 1 << (bits - 1)
-    parts = []
-    for lane in range(lanes):
-        v = (u >> (bits * lane)) & mask
-        parts.append(jnp.where(v >= sign, v - (1 << bits), v))
-    # lane-interleaved along K: value k sits at (byte k//lanes, lane k%lanes)
-    return jnp.stack(parts, axis=-1).reshape(*packed.shape[:-1], bk)
+from repro.kernels.lanes import deinterleave, pick_tile
+from repro.kernels.quant_gemv.kernel import compute_dtype, k_tile, plane_dot
 
 
-def _kernel(x_ref, packed_ref, scale_ref, out_ref, *, bits: int, bk: int):
+def _kernel(x_ref, packed_ref, scale_ref, out_ref, *, bits: int, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    levels = _unpack_block(packed_ref[...], bits, bk)          # (bn, bk) int32
-    w = levels.astype(jnp.float32) * scale_ref[...].T           # (bn, bk) f32
-    x = x_ref[...].astype(jnp.float32)                          # (bm, bk)
-    out_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    out_ref[...] += plane_dot(x_ref, packed_ref[...], bits)     # (bm, bn)
+
+    @pl.when(pl.program_id(2) == k_steps - 1)
+    def _apply_scale():
+        out_ref[...] *= scale_ref[...]                          # (1, bn) bcast
 
 
 @functools.partial(
@@ -82,35 +59,35 @@ def quant_matmul_pallas(
     n = packed.shape[0]
     lanes = LANES[bits]
     assert kx == k, (kx, k)
+    if k % lanes:
+        raise ValueError(f"K={k} must be divisible by lanes={lanes}")
     out_dtype = out_dtype or x.dtype
 
     bm = min(bm, _round_up(m, 8))
-    bk = min(bk, k)
-    bn = min(bn, n)
-    if k % bk or bk % lanes:
-        raise ValueError(f"K={k} must be divisible by bk={bk} (and bk by lanes={lanes})")
-    if n % bn:
-        raise ValueError(f"N={n} must be divisible by bn={bn}")
+    bk = k_tile(k, bits, bk)
+    bn = pick_tile(n, bn, 128)
     m_pad = _round_up(m, bm)
     if m_pad != m:
         x = jnp.pad(x, ((0, m_pad - m), (0, 0)))
+    xp = deinterleave(x.astype(compute_dtype(x.dtype)), lanes, 0)  # (lanes, M, K/lanes)
 
-    grid = (m_pad // bm, n // bn, k // bk)
+    k_steps = k // bk
+    bkp = bk // lanes
     out = pl.pallas_call(
-        functools.partial(_kernel, bits=bits, bk=bk),
-        grid=grid,
+        functools.partial(_kernel, bits=bits, k_steps=k_steps),
+        grid=(m_pad // bm, n // bn, k_steps),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bn, bk // lanes), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((lanes, bm, bkp), lambda i, j, kk: (0, i, kk)),
+            pl.BlockSpec((bn, bkp), lambda i, j, kk: (j, kk)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m_pad, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(x, packed, scale)
+    )(xp, packed, scale)
     return out[:m].astype(out_dtype)
 
 

@@ -19,7 +19,6 @@ benchmarks/roofline.py and EXPERIMENTS.md.
 import argparse
 import gzip
 import json
-import re
 import time
 import traceback
 
@@ -31,7 +30,7 @@ from repro import roofline
 from repro.roofline import hlo_cost
 from repro.configs import ARCH_MODULES, applicable_shapes, get_config
 from repro.configs.base import SHAPES, ArchConfig, ShapeSpec
-from repro.core.policy import BitPolicy
+from repro.core.policy import BitPolicy, scheme_policy
 from repro.dist import sharding
 from repro.launch import specs as specs_mod
 from repro.launch.mesh import make_production_mesh
@@ -39,30 +38,6 @@ from repro.models import registry
 from repro.quant import apply as qapply
 from repro.train import optimizer as opt_mod
 from repro.train.train_step import TrainConfig, make_train_step
-
-
-# ---------------------------------------------------------------------------
-# policies for the serve cells (no weights exist in a dry-run, so the mixed
-# policy is the *representative* SigmaQuant output shape: first/embedding
-# layers high-bit, bulk at 4, periodic 6-bit risers)
-# ---------------------------------------------------------------------------
-
-
-def dryrun_policy(specs, scheme: str) -> BitPolicy:
-    if scheme.startswith("uniform"):
-        return BitPolicy.uniform(specs, int(scheme.removeprefix("uniform")))
-    assert scheme == "mixed", scheme
-    pattern = (4, 4, 6, 4)
-    bits = {}
-    for s in specs:
-        m = re.search(r"layer(\d+)", s.name)
-        if s.kind == "embedding":
-            bits[s.name] = 8
-        elif m and int(m.group(1)) == 0:
-            bits[s.name] = 8
-        else:
-            bits[s.name] = pattern[(int(m.group(1)) if m else 0) % len(pattern)]
-    return BitPolicy.from_bits(specs, bits)
 
 
 # ---------------------------------------------------------------------------
@@ -119,7 +94,7 @@ def _abstract_serve_params(cfg: ArchConfig, policy: BitPolicy):
 def build_prefill(cfg: ArchConfig, shape: ShapeSpec, mesh, scheme: str = "mixed",
                   *, sp: bool = False):
     api = registry.get_api(cfg)
-    policy = dryrun_policy(qapply.layer_specs(_abstract_params(cfg), cfg), scheme)
+    policy = scheme_policy(qapply.layer_specs(_abstract_params(cfg), cfg), scheme)
     sparams = _abstract_serve_params(cfg, policy)
     inputs = specs_mod.prefill_inputs(cfg, shape, abstract=True)
 
@@ -148,7 +123,7 @@ def build_prefill(cfg: ArchConfig, shape: ShapeSpec, mesh, scheme: str = "mixed"
 
 def build_decode(cfg: ArchConfig, shape: ShapeSpec, mesh, scheme: str = "mixed"):
     api = registry.get_api(cfg)
-    policy = dryrun_policy(qapply.layer_specs(_abstract_params(cfg), cfg), scheme)
+    policy = scheme_policy(qapply.layer_specs(_abstract_params(cfg), cfg), scheme)
     sparams = _abstract_serve_params(cfg, policy)
     inputs = specs_mod.decode_inputs(cfg, shape, abstract=True)
 

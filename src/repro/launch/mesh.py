@@ -9,11 +9,7 @@ from __future__ import annotations
 import math
 
 import jax
-
-try:  # jax >= 0.5: explicit/auto axis types exist and Auto is the default
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -36,7 +32,5 @@ def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
             f"mesh {shape} needs {need} devices, found {len(devs)} — the dry-run "
             "must set XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "any jax import (launch/dryrun.py does)")
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
-                             devices=devs[:need])
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devs[:need])

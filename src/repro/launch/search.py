@@ -46,6 +46,7 @@ from repro.configs.base import ShapeSpec
 from repro.core.controller import ControllerConfig, SigmaQuantController, SigmaQuantResult
 from repro.core.policy import COST_METRICS, Budget, PolicyArtifact
 from repro.cost import available_cost_models, get_cost_model
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry
 from repro.obs import search as obs_search
 from repro.obs import trace as obs_trace
@@ -249,6 +250,7 @@ def _parse_limits(pairs: list[str]) -> dict[str, float]:
 
 
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=sorted(ARCH_MODULES), default="gemma-2b")
     ap.add_argument("--reduced", action="store_true")

@@ -12,21 +12,54 @@ batched requests through the continuous-batching engine.
 from __future__ import annotations
 
 import argparse
-import json
 import time
 
 import jax
 import numpy as np
 
 from repro.configs import ARCH_MODULES, get_config
-from repro.core.policy import BitPolicy, PolicyArtifact
+from repro.core.policy import BitPolicy, PolicyArtifact, scheme_policy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import trace as obs_trace
 from repro.models import registry
 from repro.quant import apply as qapply
 from repro.serve.engine import Request, ServeEngine
 
 
+def build_serve_model(cfg, *, seed: int = 0, wbits: str = "float",
+                      policy_path: str | None = None):
+    """Random weights from ``seed``, in the serve layout, packed under a
+    policy -> ``(params, policy, artifact)``.
+
+    ``policy_path`` names a searched ``PolicyArtifact`` (its layer registry
+    must match the model's); otherwise ``wbits`` is ``float`` (no packing,
+    policy None), a uniform bitwidth, ``mixed`` (``core.policy.scheme_policy``)
+    or a ``BitPolicy`` JSON file.  The float trees die on return: at
+    published widths only the packed tree fits beside the decode state.
+    """
+    api = registry.get_api(cfg)
+    params = api.init(cfg, jax.random.key(seed))
+    specs = qapply.layer_specs(params, cfg)
+    sp = api.unstack(params, cfg)
+    del params
+    artifact = None
+    if policy_path is not None:
+        artifact = PolicyArtifact.load(policy_path)
+        artifact.verify_layers(specs)  # refuse a foreign layer registry
+        policy = artifact.policy
+    elif wbits == "float":
+        return sp, None, None
+    elif wbits.endswith(".json"):
+        with open(wbits) as f:
+            policy = BitPolicy.from_json(f.read())
+    else:
+        policy = scheme_policy(specs, "mixed" if wbits == "mixed"
+                               else f"uniform{wbits}")
+    return qapply.quantize_for_serve(sp, policy, cfg), policy, artifact
+
+
 def main(argv=None) -> int:
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=sorted(ARCH_MODULES), default="gemma-2b")
     ap.add_argument("--reduced", action="store_true")
@@ -63,17 +96,10 @@ def main(argv=None) -> int:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    api = registry.get_api(cfg)
-    params = api.init(cfg, jax.random.key(args.seed))
-    sp = api.unstack(params, cfg)
-
-    artifact = None
-    if args.policy is not None:
-        specs = qapply.layer_specs(params, cfg)
-        artifact = PolicyArtifact.load(args.policy)
-        artifact.verify_layers(specs)  # refuse a foreign layer registry
-        policy = artifact.policy
-        sp = qapply.quantize_for_serve(sp, artifact, cfg)
+    sp, policy, artifact = build_serve_model(cfg, seed=args.seed,
+                                             wbits=args.wbits,
+                                             policy_path=args.policy)
+    if artifact is not None:
         budget = ("; ".join(f"{it.metric}<={it.limit:g}" for it in artifact.budget.items)
                   if artifact.budget else "none")
         print(f"policy artifact {args.policy}: backend={artifact.backend} "
@@ -87,19 +113,10 @@ def main(argv=None) -> int:
             print(f"  self-speculative draft: K={artifact.draft_k} "
                   f"mean_bits={artifact.draft_policy.mean_bits():.2f} "
                   f"(DESIGN.md §13)")
-    elif args.wbits != "float":
-        specs = qapply.layer_specs(params, cfg)
-        if args.wbits.endswith(".json"):
-            policy = BitPolicy.from_json(open(args.wbits).read())
-        elif args.wbits == "mixed":
-            from repro.launch.dryrun import dryrun_policy
-            policy = dryrun_policy(specs, "mixed")
-        else:
-            policy = BitPolicy.uniform(specs, int(args.wbits))
-        sp = qapply.quantize_for_serve(sp, policy, cfg)
+    elif policy is not None:
         print(f"quantized: mean_bits={policy.mean_bits():.2f} "
               f"size={policy.model_size_mib():.2f} MiB "
-              f"(fp32 {sum(s.n_params for s in specs) * 4 / 2**20:.2f} MiB)")
+              f"(fp32 {sum(s.n_params for s in policy.layers) * 4 / 2**20:.2f} MiB)")
 
     rng = np.random.default_rng(args.seed)
     reqs = [Request(uid=i,

@@ -334,10 +334,6 @@ def prefill_sp(params, cfg, tokens, *, mesh, qimpl="auto"):
     reduction for GQA archs before even counting the removed MLP collective.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
@@ -369,15 +365,12 @@ def prefill_sp(params, cfg, tokens, *, mesh, qimpl="auto"):
     n_layers = len(params["layers"])
     kv_spec = {"k": P(batch_axes, "model", None, None),
                "v": P(batch_axes, "model", None, None)}
-    kwargs = dict(mesh=mesh,
-                  in_specs=(jax.tree.map(lambda _: P(), params),
-                            P(batch_axes, "model")),
-                  out_specs=(P(batch_axes, "model", None),
-                             [kv_spec] * n_layers))
-    try:
-        fn = shard_map(body, check_vma=False, **kwargs)
-    except TypeError:  # older jax spells the replication check check_rep
-        fn = shard_map(body, check_rep=False, **kwargs)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(jax.tree.map(lambda _: P(), params),
+                                 P(batch_axes, "model")),
+                       out_specs=(P(batch_axes, "model", None),
+                                  [kv_spec] * n_layers),
+                       check_vma=False)
     logits_all, caches = fn(params, tokens)
     # dim1 stacks each rank's local-last logits; the global last is rank -1
     return logits_all[:, -1:], caches

@@ -31,6 +31,26 @@ class TestLayerSpecs:
             w = qapply.get_weight(params, s.name)
             assert tuple(w.shape) == s.shape, s.name
 
+    def test_scheme_policies(self, setup):
+        """``mixed``: embedding and layer 0 at 8 bits, layer i > 0 at
+        MIXED_PATTERN[i % 4], unindexed layers at the pattern's first
+        entry; ``uniform<b>``: b everywhere."""
+        import re
+
+        from repro.core.policy import MIXED_PATTERN, scheme_policy
+        cfg, api, params = setup
+        specs = qapply.layer_specs(params, cfg)
+        mixed = scheme_policy(specs, "mixed")
+        for s in specs:
+            m = re.search(r"layer(\d+)", s.name)
+            i = int(m.group(1)) if m else None
+            want = (8 if s.kind == "embedding" or i == 0
+                    else MIXED_PATTERN[(i or 0) % len(MIXED_PATTERN)])
+            assert mixed.bits[s.name] == want, s.name
+        assert set(scheme_policy(specs, "uniform6").bits.values()) == {6}
+        with pytest.raises(ValueError, match="scheme"):
+            scheme_policy(specs, "random")
+
     def test_deterministic_order(self, setup):
         cfg, api, params = setup
         a = [s.name for s in qapply.layer_specs(params, cfg)]

@@ -11,8 +11,10 @@ import numpy as np
 import pytest
 
 import repro.kernels.quant_matmul.ops as qops
-from repro.kernels.quant_gemv.kernel import GEMV_MAX_M, quant_gemv_pallas
+from repro.core.packing import LANES
+from repro.kernels.quant_gemv.kernel import GEMV_MAX_M, k_tile, quant_gemv_pallas
 from repro.kernels.quant_gemv.ref import quant_gemv_ref
+from repro.kernels.quant_matmul.kernel import quant_matmul_pallas
 from repro.kernels.quant_matmul.ops import qt_matmul, quant_matmul, resolve_kernel
 from repro.quant import apply as qapply
 from repro.quant.tensor import concat_quantized, quantize_tensor
@@ -59,6 +61,31 @@ class TestQuantGemvKernel:
         scale = qt.scale.reshape(1, -1)
         out = quant_gemv_pallas(x, qt.packed, scale, bits=4, k=256, interpret=True)
         ref = quant_gemv_ref(x, qt.packed, scale, 4, 256)
+        assert _rel(out, ref) <= 1e-5
+
+    @pytest.mark.parametrize("bits", BITS)
+    def test_k_tiles_divide_k(self, bits):
+        """K tiles are divisors of K whose packed width is a multiple of 128
+        lanes, or all of K: Yi-6B's w_down (K = 11008 = 2^8 * 43) tiles at
+        256 wherever 128 lanes fit a divisor, and whole at 2 bits."""
+        bk = k_tile(11_008, bits, 1024)
+        assert 11_008 % bk == 0
+        assert bk == 11_008 if bits == 2 else bk == 256
+        assert k_tile(4096, bits, 1024) == 1024
+
+    @pytest.mark.parametrize("kernel", ["gemv", "gemm"])
+    @pytest.mark.parametrize("bits", BITS)
+    def test_k_not_a_power_of_two(self, kernel, bits):
+        """K = 3 * 128 * lanes runs as three K steps (the accumulate-then-
+        scale loop over a non-power-of-two K) and matches the reference."""
+        lanes = LANES[bits]
+        k = 3 * 128 * lanes
+        x, qt = _case(bits, 4 if kernel == "gemv" else 24, k=k, n=128)
+        scale = qt.scale.reshape(1, -1)
+        fn = quant_gemv_pallas if kernel == "gemv" else quant_matmul_pallas
+        out = fn(x, qt.packed, scale, bits=bits, k=k, bk=128 * lanes,
+                 interpret=True)
+        ref = quant_gemv_ref(x, qt.packed, scale, bits, k)
         assert _rel(out, ref) <= 1e-5
 
     def test_rejects_wide_m(self):
