@@ -1,0 +1,2 @@
+"""On-chip serving benchmark: ``python chipbench/run.py --workload <cell>
+--seed <n> --seconds <s> --trace <0|1>`` (see ``run.py``)."""
