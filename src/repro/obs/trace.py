@@ -11,10 +11,14 @@ Design constraints, in priority order:
    deferred to :meth:`Tracer.chrome_trace`.
 3. **One clock.**  All timestamps are ``time.perf_counter()`` seconds
    (monotonic); export converts to the microseconds Perfetto expects,
-   rebased to the tracer's enable time so traces start near zero.
+   rebased to the tracer's enable time so traces start near zero.  Every
+   live span also opens a ``jax.profiler.TraceAnnotation`` of its name, so
+   a JAX profiler capture taken meanwhile shows the same spans on the
+   device trace's clock.  jax is imported on the first ``enable()``, so
+   importing this module stays stdlib-only.
 
 Tracks (Perfetto "threads") are plain strings — ``"engine"`` for the serve
-loop's step-phase spans, ``"req/<uid>"`` for per-request lifecycle spans,
+loop's step-phase spans, ``"req/<uid>"`` for per-request queue waits,
 ``"kernel"`` for autotuner timings — mapped to stable integer ``tid``s at
 record time and named via ``thread_name`` metadata on export.
 
@@ -53,9 +57,13 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """A live span: times its ``with`` body and records one "X" event."""
+    """A live span: times its ``with`` body and records one "X" event.
 
-    __slots__ = ("_tracer", "name", "cat", "track", "args", "_hist", "t0")
+    The profiler annotation opens before the first clock read and closes
+    after the second, so the recorded duration leaves out its cost."""
+
+    __slots__ = ("_tracer", "name", "cat", "track", "args", "_hist", "t0",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, track: str,
                  hist, args):
@@ -72,6 +80,8 @@ class _Span:
         self.args.update(kw)
 
     def __enter__(self):
+        self._ann = self._tracer._annotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -81,6 +91,7 @@ class _Span:
             ("X", self.name, self.cat, self.track, self.t0, dur, self.args))
         if self._hist is not None:
             self._hist.observe(dur)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -97,6 +108,8 @@ class Tracer:
         self.enabled = False
         self._events: list[tuple] = []
         self._t0 = 0.0
+        #: ``jax.profiler.TraceAnnotation``, bound on the first enable()
+        self._annotation = None
 
     # -- lifecycle ---------------------------------------------------------
     def enable(self, *, clear: bool = True) -> None:
@@ -104,6 +117,9 @@ class Tracer:
             self.clear()
         if not self._events:
             self._t0 = time.perf_counter()
+        if self._annotation is None:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:
@@ -120,7 +136,8 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, *, cat: str = "span", track: str = "engine",
              hist=None, args: dict | None = None):
-        """Context manager timing its body.  ``hist`` (an
+        """Context manager timing its body, mirrored as a JAX profiler
+        annotation of the same name.  ``hist`` (an
         ``obs.metrics.Histogram``) additionally receives the duration in
         seconds on exit, so trace events and metrics stay in lock-step."""
         if not self.enabled:
@@ -129,7 +146,8 @@ class Tracer:
 
     def complete(self, name: str, *, ts: float, dur: float, cat: str = "span",
                  track: str = "engine", args: dict | None = None) -> None:
-        """Record an already-timed span (explicit start + duration)."""
+        """Record an already-timed span (explicit start + duration); not
+        mirrored to the profiler."""
         if not self.enabled:
             return
         self._events.append(("X", name, cat, track, ts, dur, args))

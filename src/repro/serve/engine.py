@@ -321,7 +321,8 @@ class ServeEngine:
         self._install_kernel_configs()
         # observability (DESIGN.md §16): the metrics registry is the source
         # of truth behind the legacy stats() dict; the process-wide tracer
-        # adds step-phase + lifecycle spans when (and only when) enabled
+        # adds step-phase, admission and queue-wait spans when (and only
+        # when) enabled
         self.metrics = obs_metrics.MetricsRegistry()
         for name in _COUNTER_KEYS:
             self.metrics.counter(name)
@@ -334,8 +335,8 @@ class ServeEngine:
         self.metrics.histogram("itl_s")
         self._tracer = obs_trace.get_tracer()
         self._shed_events: list[dict] = []
-        #: uid -> perf_counter start of the request's current lifecycle
-        #: segment (tracing only)
+        #: uid -> perf_counter start of the request's current wait in the
+        #: queue (tracing only)
         self._lc_marks: dict[int, float] = {}
         # graceful degradation (DESIGN.md §14): the live burst K walks the
         # shed ladder under pool pressure; tier index 0 = full service
@@ -436,13 +437,6 @@ class ServeEngine:
                 f"policy artifact kernel_configs do not fit this "
                 f"deployment: {e}") from e
         autotune.set_active_configs(entries)
-        # replayed configs land in the trace next to the live step times, so
-        # a Perfetto timeline shows WHICH searched layout each step ran
-        tr = obs_trace.get_tracer()
-        if tr.enabled:
-            for e in entries:
-                tr.instant("kernel_config_replayed", cat="kernel",
-                           track="kernel", args=dict(e))
 
     # -- observability (DESIGN.md §16) ------------------------------------
     def _count(self, name: str, n: int = 1) -> None:
@@ -461,33 +455,34 @@ class ServeEngine:
                        hist=self.metrics.histogram("phase/" + name),
                        args=args or None)
 
+    def _admit_span(self, name: str, n: int, pad: int):
+        """A span of admission's work (``prefill_dispatch``, ``kv_insert``)
+        inside the ``admission`` or ``prefill_chunk`` phase: category
+        ``admit``, so phase totals count its time once.  ``n`` requests at
+        padded length ``pad``."""
+        tr = self._tracer
+        if not tr.enabled:
+            return obs_trace.NOOP_SPAN
+        return tr.span(name, cat="admit", track="engine",
+                       args={"n": n, "pad": pad})
+
     def _observe_transition(self, lc: RequestLifecycle, old: RequestState,
                             new: RequestState, now: float,
                             diagnostic: str) -> None:
-        """Lifecycle observer: close the span for the segment that just
-        ended on the request's own trace track, mark terminal states with
-        an instant.  Keyed off the SAME validated transitions the resource
-        accounting uses (serve/lifecycle.py)."""
+        """Lifecycle observer: a ``queued`` span on the request's own trace
+        track for each stretch it waits in the queue (from submit, a
+        preemption or a rolled-back admission, to its admission or end).
+        Keyed off the SAME validated transitions the resource accounting
+        uses (serve/lifecycle.py)."""
+        t0 = self._lc_marks.pop(lc.uid, None)
         tr = self._tracer
         if not tr.enabled:
-            self._lc_marks.pop(lc.uid, None)
             return
         t = tr.now()
-        track = f"req/{lc.uid}"
-        t0 = self._lc_marks.pop(lc.uid, None)
         if t0 is not None:
             tr.complete(old.value, ts=t0, dur=t - t0, cat="request",
-                        track=track, args={"uid": lc.uid})
-        if new in (RequestState.DONE, RequestState.FAILED,
-                   RequestState.CANCELLED, RequestState.TIMED_OUT):
-            tr.instant(new.value, cat="request", track=track,
-                       args={"uid": lc.uid,
-                             "diagnostic": diagnostic or lc.diagnostic,
-                             "preemptions": lc.preemptions})
-        else:
-            if new is RequestState.QUEUED:  # preemption / admission rollback
-                tr.instant("requeued", cat="request", track=track,
-                           args={"uid": lc.uid, "diagnostic": diagnostic})
+                        track=f"req/{lc.uid}", args={"uid": lc.uid})
+        if new is RequestState.QUEUED:
             self._lc_marks[lc.uid] = t
 
     # -- fault injection (runtime/resilience.py) ---------------------------
@@ -845,8 +840,6 @@ class ServeEngine:
         ev = {"action": action, "step": self._nsteps(),
               "tier": self._shed_tier, "k": self._k_live, **extra}
         self._shed_events.append(ev)
-        self._tracer.instant("shed:" + action, cat="degradation",
-                             track="engine", args=ev)
 
     def _maybe_shed(self, waiting: list[Request]) -> bool:
         """ONE degradation action for this loop turn (True if state changed):
@@ -941,10 +934,6 @@ class ServeEngine:
         tr = self._tracer
         if tr.enabled:
             self._lc_marks[req.uid] = tr.now()
-            tr.instant("submit", cat="request", track=f"req/{req.uid}",
-                       args={"uid": req.uid, "priority": req.priority,
-                             "prompt_tokens": len(req.prompt),
-                             "max_new_tokens": req.max_new_tokens})
         self.lifecycles[req.uid] = lc
         if on_token is not None:
             self._on_token[req.uid] = on_token
@@ -1127,18 +1116,22 @@ class ServeEngine:
         if self.paged:
             self._push_tables()
         if with_head:
+            n = len(with_head)
             pad = min(_round_up(max(len(h) for _, h in with_head),
                                 self.prefill_pad), self.max_seq)
-            toks = np.zeros((len(with_head), pad), np.int32)
-            for row, (_, head) in enumerate(with_head):
-                toks[row, : len(head)] = head
-            lengths = jnp.asarray([len(h) for _, h in with_head], jnp.int32)
-            st = self._prefill(self.params, jnp.asarray(toks), lengths)
-            if self.paged:
-                self._insert_rows_paged(with_head, st, lengths, pad)
-            else:
-                self._insert_rows([slot_id for slot_id, _ in with_head], st,
-                                  lengths)
+            with self._admit_span("prefill_dispatch", n, pad):
+                toks = np.zeros((n, pad), np.int32)
+                for row, (_, head) in enumerate(with_head):
+                    toks[row, : len(head)] = head
+                lengths = jnp.asarray([len(h) for _, h in with_head],
+                                      jnp.int32)
+                st = self._prefill(self.params, jnp.asarray(toks), lengths)
+            with self._admit_span("kv_insert", n, pad):
+                if self.paged:
+                    self._insert_rows_paged(with_head, st, lengths, pad)
+                else:
+                    self._insert_rows([slot_id for slot_id, _ in with_head],
+                                      st, lengths)
             self._count("prefill_tokens", sum(len(h) for _, h in with_head))
         now = time.monotonic()
         for req in admitted:
@@ -1205,22 +1198,23 @@ class ServeEngine:
                                        jnp.asarray(self._chunk_head[slot_id]),
                                        jnp.asarray([p + n], jnp.int32))
                 jax.block_until_ready(st)
-            s.pos = p + n
-            self._count("prefill_tokens", n)
-            self._count("prefill_chunks")
-            lc = self.lifecycles.get(req.uid)
-            if lc is not None:
-                lc.prefill_progress = s.pos
-            if self.paged:
-                # map the blocks this chunk fully filled against the
-                # admission-time reservation; the partial block stays
-                # unmapped so the reservation ledger keeps matching
-                # _required_growth exactly (and the zero-beyond-write probe
-                # never reads a mapped-but-unwritten block)
-                for tb in range(p // blk, s.pos // blk):
-                    self._host_tables[slot_id, tb] = self._grow_alloc(slot_id)
-            if s.pos >= w:
-                self._finish_prefill(slot_id, st)
+                s.pos = p + n
+                self._count("prefill_tokens", n)
+                self._count("prefill_chunks")
+                lc = self.lifecycles.get(req.uid)
+                if lc is not None:
+                    lc.prefill_progress = s.pos
+                if self.paged:
+                    # map the blocks this chunk fully filled against the
+                    # admission-time reservation; the partial block stays
+                    # unmapped so the reservation ledger keeps matching
+                    # _required_growth exactly (and the zero-beyond-write
+                    # probe never reads a mapped-but-unwritten block)
+                    for tb in range(p // blk, s.pos // blk):
+                        self._host_tables[slot_id, tb] = \
+                            self._grow_alloc(slot_id)
+                if s.pos >= w:
+                    self._finish_prefill(slot_id, st)
 
     def _finish_prefill(self, slot_id: int, st) -> None:
         """Final chunk landed: insert the carried state into the live cache
@@ -1230,17 +1224,20 @@ class ServeEngine:
         s = self.slots[slot_id]
         prompt = s.req.prompt
         w = len(prompt) - 1
-        lengths = jnp.asarray([w], jnp.int32)
+        pad = self._chunk_head[slot_id].shape[1]
         if self.paged:
             blk = self._kv_blk
             for tb in range((w - 1) // blk + 1):
                 if self._host_tables[slot_id, tb] < 0:
                     self._host_tables[slot_id, tb] = self._grow_alloc(slot_id)
-            pad = self._chunk_head[slot_id].shape[1]
-            self._insert_rows_paged([(slot_id, prompt[:-1])], st, lengths, pad)
             self._tables_dirty = True  # real row replaces the -1 mask
-        else:
-            self._insert_rows([slot_id], st, lengths)
+        with self._admit_span("kv_insert", 1, pad):
+            lengths = jnp.asarray([w], jnp.int32)
+            if self.paged:
+                self._insert_rows_paged([(slot_id, prompt[:-1])], st,
+                                        lengths, pad)
+            else:
+                self._insert_rows([slot_id], st, lengths)
         s.prefilling = False
         s.pos = w
         self._pending_token[slot_id] = prompt[-1]  # replayed next step
@@ -1265,7 +1262,8 @@ class ServeEngine:
 
         With the process-wide tracer enabled (``repro.obs.trace.enable()``)
         every turn additionally records a ``step`` span decomposed into the
-        named phases of ``_turn`` plus per-request lifecycle spans — see
+        named phases of ``_turn``, admission's ``admit`` spans and each
+        request's ``queued`` spans — see
         ``trace_report()`` and DESIGN.md §16.  Tracing never changes the
         dispatch or sampling math, so traced runs are token-identical to
         untraced runs.
@@ -1298,12 +1296,6 @@ class ServeEngine:
                 with self._span("bookkeeping"):
                     if dispatch_dt is not None:
                         self._after_dispatch(step_idx, dispatch_dt)
-                    if tr.enabled:
-                        tr.counter("queue_depth", len(self._queue))
-                        tr.counter("active_slots",
-                                   sum(not s.free for s in self.slots))
-                        if self.paged:
-                            tr.counter("pool_available", self.pool.available)
         self.metrics.counter("wall_s").inc(time.perf_counter() - t0)
         return results
 
@@ -1447,7 +1439,6 @@ class ServeEngine:
         append accepted tokens (recording TTFT / inter-token gaps), finalize
         completed requests."""
         now = time.monotonic()
-        tr = self._tracer
         ttft_hist = self.metrics.histogram("ttft_s")
         itl_hist = self.metrics.histogram("itl_s")
         for i in act:
@@ -1458,10 +1449,6 @@ class ServeEngine:
                 # (sampling already saw zeroed logits, so neighbours'
                 # streams are untouched)
                 self._count("nan_quarantined")
-                if tr.enabled:
-                    tr.instant("nan_quarantine", cat="anomaly",
-                               track=f"req/{s.req.uid}",
-                               args={"uid": s.req.uid, "step": step})
                 self._finalize(i, s.req, RequestState.FAILED, results,
                                diagnostic=f"non-finite logits at decode "
                                           f"step {step}")
@@ -1476,11 +1463,6 @@ class ServeEngine:
                 if lc is not None and lc.first_token_t is None:
                     lc.first_token_t = now
                     ttft_hist.observe(now - lc.enqueued_t)
-                    if tr.enabled:
-                        tr.instant("first_token", cat="request",
-                                   track=f"req/{lc.uid}",
-                                   args={"uid": lc.uid,
-                                         "ttft_s": now - lc.enqueued_t})
                 if s.last_token_t is not None:
                     # tokens of one speculative burst land together: only
                     # the first gap of the turn is a real inter-token wait

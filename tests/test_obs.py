@@ -345,39 +345,12 @@ def test_lifecycle_spans_done(setup):
     eng.run(_requests(n=1))
     tr = obs_trace.get_tracer()
     evs = _events_on(tr, "req/0")
-    names = [e[1] for e in evs]
-    assert "submit" in names and "first_token" in names
-    # one closed span per traversed segment + the terminal instant
-    spans = [e[1] for e in evs if e[0] == "X"]
-    assert spans == ["queued", "prefill", "decode"]
-    assert names[-1] == "done"
-
-
-def test_lifecycle_spans_failed(setup):
-    cfg, sp = setup
-    inj = FailureInjector(schedule={"nan_logit": (1,)})
-    eng = _engine(cfg, sp, state_bits=8, fault_injector=inj)
-    obs_trace.enable()
-    eng.run(_requests(n=1))
-    assert eng.lifecycles[0].state is RequestState.FAILED
-    tr = obs_trace.get_tracer()
-    names = [e[1] for e in _events_on(tr, "req/0")]
-    assert "nan_quarantine" in names and names[-1] == "failed"
-
-
-def test_lifecycle_spans_cancelled(setup):
-    cfg, sp = setup
-    eng = _engine(cfg, sp)
-
-    def hook(engine, step):
-        engine.cancel(0)
-
-    obs_trace.enable()
-    eng.run(_requests(n=1, max_new=32), step_hook=hook)
-    assert eng.lifecycles[0].state is RequestState.CANCELLED
-    tr = obs_trace.get_tracer()
-    names = [e[1] for e in _events_on(tr, "req/0")]
-    assert names[-1] == "cancelled"
+    # the request's one lifecycle event: its wait from submit to admission
+    assert [e[:3] for e in evs] == [("X", "queued", "request")]
+    assert evs[0][6] == {"uid": 0}
+    q_end = evs[0][4] + evs[0][5]
+    assert any(e[1] == "admission" and e[4] <= q_end <= e[4] + e[5]
+               for e in tr.events())
 
 
 def test_lifecycle_spans_timed_out(setup):
@@ -389,9 +362,39 @@ def test_lifecycle_spans_timed_out(setup):
     assert eng.lifecycles[0].state is RequestState.TIMED_OUT
     tr = obs_trace.get_tracer()
     evs = _events_on(tr, "req/0")
-    # never admitted: the queued segment closes, then the terminal instant
-    assert [e[1] for e in evs if e[0] == "X"] == ["queued"]
-    assert [e[1] for e in evs][-1] == "timed_out"
+    # never admitted: the queued segment closes at the expiry
+    assert [e[1] for e in evs] == ["queued"]
+
+
+def _finish_as(outcome):
+    """(engine kwargs, step hook, request) ending one request ``outcome``."""
+    if outcome == "failed":
+        return ({"state_bits": 8, "fault_injector": FailureInjector(
+            schedule={"nan_logit": (1,)})}, None, _requests(n=1)[0])
+    if outcome == "cancelled":
+        return {}, lambda engine, step: engine.cancel(0), \
+            _requests(n=1, max_new=32)[0]
+    if outcome == "timed_out":
+        return {}, None, Request(uid=0, prompt=[3, 4, 5], max_new_tokens=4,
+                                 deadline_s=0.0)
+    return {}, None, _requests(n=1)[0]
+
+
+@pytest.mark.parametrize("outcome",
+                         ["done", "failed", "cancelled", "timed_out"])
+def test_queued_span_per_request(setup, outcome):
+    """Every request submitted while the tracer is on gets one ``queued``
+    span, whatever its end: the wait the benchmark's queue metric reads."""
+    cfg, sp = setup
+    kw, hook, req = _finish_as(outcome)
+    eng = _engine(cfg, sp, **kw)
+    obs_trace.enable()
+    eng.run([req], step_hook=hook)
+    assert eng.lifecycles[0].state.value == outcome
+    evs = _events_on(obs_trace.get_tracer(), "req/0")
+    assert [(e[1], e[2], e[6]) for e in evs] == [
+        ("queued", "request", {"uid": 0})]
+    assert evs[0][5] >= 0
 
 
 def test_lifecycle_spans_preempted_requeue(setup):
@@ -412,12 +415,10 @@ def test_lifecycle_spans_preempted_requeue(setup):
     assert len(out[0]) == 24
     tr = obs_trace.get_tracer()
     evs = _events_on(tr, "req/0")
-    names = [e[1] for e in evs]
-    assert "requeued" in names
-    spans = [e[1] for e in evs if e[0] == "X"]
-    # the preempted request traverses decode twice around the re-queue
-    assert spans.count("decode") == 2 and spans.count("prefill") == 2
-    assert names[-1] == "done"
+    # the preempted request waits in the queue twice: from submit, and
+    # from its preemption to its second admission
+    assert [e[1] for e in evs] == ["queued", "queued"]
+    assert evs[0][4] + evs[0][5] <= evs[1][4]
 
 
 def test_kernel_config_replay_traced(setup):
@@ -460,3 +461,219 @@ def test_tracing_overhead_bounded(setup):
     traced = timed(True)
     obs_trace.disable()
     assert traced <= untraced * 3 + 0.05, (traced, untraced)
+
+
+# ---------------------------------------------------------------------------
+# the profiler's clock: live spans mirrored as TraceAnnotations
+# ---------------------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs enter/exit with
+    the clock reading at each."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        import time
+        self.log.append(("enter", self.name, time.perf_counter()))
+
+    def __exit__(self, *exc):
+        import time
+        self.log.append(("exit", self.name, time.perf_counter()))
+
+
+@pytest.mark.parametrize("kind", ["span", "complete", "instant", "counter"])
+def test_only_live_spans_open_annotations(kind):
+    t = Tracer()
+    t.enable()
+    t._annotation = _FakeAnnotation
+    _FakeAnnotation.log = []
+    if kind == "span":
+        with t.span("admission", cat="phase"):
+            pass
+    elif kind == "complete":
+        t.complete("queued", ts=t.now(), dur=0.0, cat="request")
+    elif kind == "instant":
+        t.instant("mark")
+    else:
+        t.counter("depth", 1.0)
+    log = _FakeAnnotation.log
+    if kind != "span":        # retroactive and point events: not mirrored
+        assert log == []
+        return
+    (enter, name, t_in), (leave, name2, t_out) = log
+    assert (enter, leave, name, name2) == ("enter", "exit", "admission",
+                                           "admission")
+    (ev,) = t.events()
+    # the annotation opens before the span's first clock read and closes
+    # after its second: the recorded duration leaves out its cost
+    assert t_in <= ev[4] and ev[4] + ev[5] <= t_out
+
+
+@pytest.mark.parametrize("enable", [False, True])
+def test_obs_imports_jax_only_on_enable(enable):
+    """Importing ``repro.obs`` stays stdlib-only; the first ``enable()``
+    binds ``jax.profiler.TraceAnnotation``."""
+    import subprocess
+    import sys
+    code = ("import sys; from repro.obs import trace; "
+            "assert 'jax' not in sys.modules; "
+            + ("trace.enable(); from jax.profiler import TraceAnnotation; "
+               "assert trace.get_tracer()._annotation is TraceAnnotation; "
+               if enable else "")
+            + "print('ok')")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert out.stdout.strip() == "ok", out.stderr
+
+
+def test_profiler_capture_shows_engine_spans(setup, tmp_path):
+    """A JAX profiler capture taken while the tracer is on holds every
+    engine phase and admission span of the run on its host plane, each at
+    least as long as the span the tracer recorded."""
+    import glob
+    from jax.profiler import ProfileData
+
+    cfg, sp = setup
+    eng = _engine(cfg, sp)
+    eng.run(_requests(n=2))          # compile outside the capture
+    jax.profiler.start_trace(str(tmp_path))
+    obs_trace.enable()
+    try:
+        eng.run(_requests(n=2))
+    finally:
+        obs_trace.disable()
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(e.duration_ns / 1e9)
+    spans = {}
+    for ev in obs_trace.get_tracer().events():
+        if ev[0] == "X" and ev[2] in ("phase", "admit", "step"):
+            spans.setdefault(ev[1], []).append(ev[5])
+    assert {"admission", "kv_insert", "prefill_dispatch", "step"} <= set(spans)
+    for name, durs in spans.items():
+        assert len(host.get(name, [])) == len(durs), name
+        assert max(host[name]) >= max(durs), name
+
+
+# ---------------------------------------------------------------------------
+# admission sub-spans (category ``admit``)
+# ---------------------------------------------------------------------------
+
+ADMIT_MODES = {
+    "dense": {},
+    "paged": {"state_bits": 4, "paged": True, "pool_blocks": 24},
+    "chunked": {"prefill_chunk": 3},
+    "chunked-paged": {"prefill_chunk": 3, "state_bits": 4, "paged": True,
+                      "pool_blocks": 24},
+}
+
+
+def _phase_events(events):
+    return [e for e in events if e[2] == "phase"]
+
+
+@pytest.fixture(scope="module", params=list(ADMIT_MODES))
+def admit_runs(request, setup):
+    """Per engine mode: one run untraced, a twin traced, and a third traced
+    with the admission sub-spans switched off."""
+    from types import SimpleNamespace
+
+    cfg, sp = setup
+    kw = ADMIT_MODES[request.param]
+    tr = obs_trace.get_tracer()
+    tr.clear()
+    plain = _engine(cfg, sp, **kw)
+    ref = plain.run(_requests())
+    untraced_events = tr.events()
+
+    def traced_run(eng):
+        obs_trace.enable()
+        try:
+            out = eng.run(_requests())
+        finally:
+            obs_trace.disable()
+        events = tr.events()
+        tr.clear()
+        return out, events
+
+    traced = _engine(cfg, sp, **kw)
+    out, events = traced_run(traced)
+    bare = _engine(cfg, sp, **kw)
+    bare._admit_span = lambda *a: NOOP_SPAN
+    _, bare_events = traced_run(bare)
+    return SimpleNamespace(chunked="prefill_chunk" in kw, plain=plain,
+                           ref=ref, untraced_events=untraced_events,
+                           traced=traced, out=out, events=events,
+                           bare_events=bare_events)
+
+
+def test_admit_spans_nest_in_phase(admit_runs):
+    """Each admitted request is counted by one ``kv_insert`` span and one
+    ``prefill_dispatch`` span (chunked: ``kv_insert`` alone, at the final
+    chunk), each inside the phase that does the admission."""
+    r = admit_runs
+    admit = [e for e in r.events if e[2] == "admit"]
+    names = {"kv_insert"} if r.chunked else {"kv_insert", "prefill_dispatch"}
+    assert {e[1] for e in admit} == names
+    for name in names:
+        spans = [e for e in admit if e[1] == name]
+        assert sum(e[6]["n"] for e in spans) == len(_requests())
+        assert all(e[3] == "engine" and e[6]["pad"] == 8 for e in spans)
+    parent = "prefill_chunk" if r.chunked else "admission"
+    phases = [e for e in r.events if e[2] == "phase" and e[1] == parent]
+    for e in admit:
+        assert any(p[4] <= e[4] and e[4] + e[5] <= p[4] + p[5]
+                   for p in phases), e
+    if not r.chunked:     # the dispatch comes first, then the insertion
+        order = [e[1] for e in sorted(admit, key=lambda e: e[4])]
+        assert order == ["prefill_dispatch", "kv_insert"] * (len(order) // 2)
+
+
+def test_tracer_off_records_nothing(admit_runs):
+    """Disabled, the run records no event and fills no phase histogram, and
+    its stats() counters are those of the traced twin."""
+    r = admit_runs
+    assert r.untraced_events == []
+    assert not [k for k in r.plain.metrics.snapshot() if "/" in k]
+    a, b = r.plain.stats(), r.traced.stats()
+    assert set(a) == set(b) and set(a["health"]) == set(b["health"])
+    for key in ("prefill_tokens", "decode_steps", "loop_turns", "completed",
+                "prefill_chunks", "shed_events"):
+        assert a[key] == b[key], key
+
+
+def test_traced_tokens_match_untraced(admit_runs):
+    assert admit_runs.out == admit_runs.ref
+
+
+def test_admit_spans_leave_phase_totals(admit_runs):
+    """The ``admit`` spans are counted in no phase: the phase events of a
+    traced run are those of a run without them, the phase histograms hold
+    exactly the phase events, and the attributed fraction is theirs."""
+    r = admit_runs
+    key = lambda e: (e[1], e[2])
+    assert sorted(map(key, _phase_events(r.events))) == \
+        sorted(map(key, _phase_events(r.bare_events)))
+    assert not [e for e in r.bare_events if e[2] == "admit"]
+    rep = r.traced.trace_report()
+    assert not set(rep["phases"]) & {"kv_insert", "prefill_dispatch"}
+    totals = {}
+    for e in _phase_events(r.events):
+        totals[e[1]] = totals.get(e[1], 0.0) + e[5]
+    assert set(rep["phases"]) == set(totals)
+    for name, total in totals.items():
+        assert rep["phases"][name]["total_s"] == pytest.approx(total)
+    steps = sum(e[5] for e in r.events if e[2] == "step")
+    assert rep["attributed_fraction"] == pytest.approx(
+        sum(totals.values()) / steps)
+    assert rep["attributed_fraction"] <= 1.0
