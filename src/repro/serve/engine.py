@@ -8,7 +8,10 @@ Design (vLLM-style, adapted to JAX's static shapes):
     ONE batch — the prompts (minus their last tokens) right-pad to the
     group max rounded to ``prefill_pad`` and prefill in a single
     ``(n_free, pad)`` call (a handful of compiled prefill shapes, not one
-    dispatch per request).  Each row tree-inserts into its slot; the next
+    dispatch per request).  The rows tree-insert into their slots in one
+    jitted program that donates the decode state, compiled once per
+    ``(rows, pad)`` shape and shared by whole-prompt and final-chunk
+    inserts (slot ids and lengths are traced arrays); the next
     decode step replays the last prompt token at ``pos = len-1`` — that both
     yields the first sampled token *and* overwrites the pad garbage at that
     position.  Pad positions beyond ``pos`` are masked by the per-slot
@@ -390,6 +393,22 @@ class ServeEngine:
         # instead of silently keeping the init-time value.
         self._decode = jax.jit(decode, donate_argnums=(1,), static_argnums=(6, 7, 8))
         self._prefill = jax.jit(prefill)
+        # admission's K/V insertion: ONE donated program per (rows, pad)
+        # shape — slot ids, valid lengths and paged row tables ride as
+        # traced int32 arrays, so neither a slot nor a prompt length
+        # retraces, and the state's buffers update in place.  Dense and
+        # paged inserts run under the same compiler, so their blocks
+        # quantize bit-identically (DESIGN.md §11/§12/§17).
+        def insert(state, ids, st_new, lengths):
+            return kvcache.insert_state_rows(state, ids, st_new, lengths)
+
+        def insert_paged(state, row_tables, st_new, lengths):
+            return [kvcache.paged.insert_prefill_rows(
+                        layer, row_tables, new["k"], new["v"], valid_len=lengths)
+                    for layer, new in zip(state, st_new)]
+
+        self._insert = jax.jit(insert, donate_argnums=(0,))
+        self._insert_paged = jax.jit(insert_paged, donate_argnums=(0,))
         # chunked prefill: one donated-scratch dispatch per chunk.  The
         # offset rides as a traced scalar so every chunk of a prompt reuses
         # ONE compilation per (scratch_len, chunk) shape pair.
@@ -610,10 +629,12 @@ class ServeEngine:
         fp leaves scatter directly (one scatter per leaf, no per-row
         full-cache copies); quantized KV layers quantize the fp prefill
         rows block-wise on the way in — kvcache.insert_state_rows is the
-        shared walker (the calibration env admits the same way).
+        shared walker (the calibration env admits the same way, eagerly).
+        One dispatch of the donated ``_insert`` program: the host returns
+        before the device has run it.
         """
-        self.state = kvcache.insert_state_rows(self.state, jnp.asarray(slot_ids),
-                                               st_new, lengths)
+        self.state = self._insert(self.state, jnp.asarray(slot_ids, jnp.int32),
+                                  st_new, lengths)
 
     # -- paged block bookkeeping (DESIGN.md §12) --------------------------
     def _push_tables(self) -> None:
@@ -1045,12 +1066,8 @@ class ServeEngine:
         return out
 
     def _insert_rows_paged(self, with_head, st_new, lengths, pad: int) -> None:
-        row_tables = self._row_tables(with_head, pad)
-        new_state = []
-        for layer, new in zip(self.state, st_new):
-            new_state.append(kvcache.paged.insert_prefill_rows(
-                layer, row_tables, new["k"], new["v"], valid_len=lengths))
-        self.state = new_state
+        row_tables = jnp.asarray(self._row_tables(with_head, pad))
+        self.state = self._insert_paged(self.state, row_tables, st_new, lengths)
 
     # -- admission ---------------------------------------------------------
     def _admit(self, assignments: list[tuple[int, Request]]) -> list[Request]:

@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import kvcache
 from repro.configs import gemma_2b, mamba2_2p7b, zamba2_2p7b
+from repro.core import packing
 from repro.core.controller import SigmaQuantController
 from repro.core.policy import BitPolicy, Budget, LayerInfo, PolicyArtifact
 from repro.cost import RooflineCostModel, ShiftAddCostModel
@@ -359,6 +361,42 @@ class TestEngineQuantizedStateDonation:
                                     eng.temperature, eng.top_k, eng.top_p)
         txt = lowered.as_text()
         assert "tf.aliasing_output" in txt or "jax.buffer_donor" in txt
+
+
+class TestJittedInsert:
+    @pytest.mark.parametrize("state_bits", [4, 8, None], ids=["q4", "q8", "fp"])
+    def test_jitted_insert_matches_eager(self, dense_setup, state_bits):
+        """The engine's donated insert program writes what the eager walker
+        writes: levels exactly, scales to float32 rounding, and zero levels
+        from the valid length on."""
+        cfg, _, sp = dense_setup
+        eng = ServeEngine(cfg, sp, max_slots=3, max_seq=64,
+                          state_bits=state_bits)
+        pad, valid, slot = 16, 11, 2
+        toks = jnp.asarray(np.random.default_rng(0).integers(1, 500, (1, pad)),
+                           jnp.int32)
+        lengths = jnp.asarray([valid], jnp.int32)
+        st = eng._prefill(eng.params, toks, lengths)
+        ids = jnp.asarray([slot], jnp.int32)
+        eager = kvcache.insert_state_rows(eng.state, ids, st, lengths)
+        jitted = eng._insert(eng.state, ids, st, lengths)
+        for e, j in zip(eager, jitted):
+            if state_bits is None:
+                for side in "kv":
+                    np.testing.assert_array_equal(np.asarray(j[side]),
+                                                  np.asarray(e[side]))
+                continue
+            for side, bits in (("k", j.k_bits), ("v", j.v_bits)):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(j, f"{side}_packed")),
+                    np.asarray(getattr(e, f"{side}_packed")))
+                np.testing.assert_allclose(
+                    np.asarray(getattr(j, f"{side}_scale")),
+                    np.asarray(getattr(e, f"{side}_scale")), rtol=1e-6)
+                lev = np.asarray(packing.unpack(getattr(j, f"{side}_packed"),
+                                                bits, j.head_dim))
+                assert lev[slot, :, :valid].any()
+                assert not lev[slot, :, valid:].any()
 
 
 # ---------------------------------------------------------------------------

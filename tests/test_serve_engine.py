@@ -167,6 +167,29 @@ def test_decode_step_donates_state(dense_setup):
     assert "tf.aliasing_output" in txt or "jax.buffer_donor" in txt
 
 
+@pytest.mark.parametrize("kw", [dict(state_bits=4),
+                                dict(state_bits=4, paged=True, pool_blocks=32)],
+                         ids=["dense", "paged"])
+def test_insert_donates_state_and_does_not_retrace(dense_setup, kw):
+    """Admission's K/V insertion is one donated jitted program per
+    (rows, pad) shape: slot ids and prompt lengths never retrace it."""
+    cfg, api, sp = dense_setup
+    eng = ServeEngine(cfg, sp, max_slots=3, max_seq=64, prefill_pad=16,
+                      batch_admission=False, **kw)
+    insert = eng._insert_paged if eng.paged else eng._insert
+    # heads of 2, 6 and 11 tokens all pad to 16, one per slot
+    outs = eng.generate([[5, 6, 7], [1, 2, 9, 4, 7, 3, 8], [9] * 12],
+                        max_new_tokens=3)
+    assert all(len(o) == 3 for o in outs)
+    assert insert._cache_size() == 1
+    lengths = jnp.asarray([5], jnp.int32)
+    st = eng._prefill(eng.params, jnp.zeros((1, 16), jnp.int32), lengths)
+    where = (jnp.ones((1, 1), jnp.int32) if eng.paged
+             else jnp.asarray([1], jnp.int32))
+    txt = insert.lower(eng.state, where, st, lengths).as_text()
+    assert "tf.aliasing_output" in txt or "jax.buffer_donor" in txt
+
+
 def test_ssm_engine():
     cfg = mamba2_2p7b.CONFIG.reduced()
     api = registry.get_api(cfg)
