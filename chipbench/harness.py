@@ -5,14 +5,23 @@ Everything that belongs to a cell is found by name: the cell's entry in
 ``BENCHMARK.json`` names its configuration (``configs/<name>.json``) and
 traffic mix (``traffic/<name>.json``); ``workloads/<cell>.json`` holds the
 engine settings and the check's limit; every metric is read by
-``metrics/<metric>.py``.  Adding a cell, configuration, mix or metric is
-adding files and entries.
+``metrics/<metric>.py``.  The configuration names its architecture
+(``architectures/<architecture>.py``, reached through ``system``) and its
+plain reference (``<reference>.py``).  Adding a cell, configuration,
+architecture, reference, mix or metric is adding files and entries.
+
+A reference module exports ``Reference(conf, seed, *, max_seq,
+max_out)`` whose ``gaps(requests, control=False)`` gives, for each
+``(prompt, served)``, the gap of every served token below the
+reference's best logit and, with ``control``, the gap of the token the
+control puts first.  It imports nothing of the program (no ``repro``, no
+``system``) and takes nothing the program made: it draws its weights
+from the seed itself.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import shutil
@@ -23,7 +32,7 @@ from pathlib import Path
 
 import numpy as np
 
-from . import costs, peaks, system, traffic
+from . import peaks, system, traffic
 
 ROOT = Path(__file__).resolve().parent
 CHECKOUT = ROOT.parent
@@ -81,12 +90,16 @@ def find_cell(name: str, bench: dict, root: Path = ROOT) -> Cell:
 
 def reader(name: str, root: Path = ROOT):
     """``metrics/<name>.py``'s ``read(ctx)``."""
-    path = root / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return system.module(
+        root / "metrics" / f"{name}.py",
+        "chipbench_metric_" + name.replace(".", "_").replace("-", "_")).read
+
+
+def reference(conf: dict, root: Path = ROOT):
+    """The reference module the configuration names: ``<reference>.py``
+    under ``root``, whose relative imports resolve in ``chipbench``."""
+    name = conf["reference"]
+    return system.module(root / f"{name}.py", f"chipbench.{name}")
 
 
 # -- the chip ------------------------------------------------------------------
@@ -287,7 +300,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     calibration of the limit, ``calibrate.py``)."""
     t_start = time.perf_counter()
     bench = benchmark(checkout)
-    cell = find_cell(name, bench, checkout / "chipbench")
+    root = checkout / "chipbench"
+    cell = find_cell(name, bench, root)
+    # a configuration whose architecture or reference has no file fails
+    # here, before anything is set up
+    system.architecture(cell.conf["architecture"], root)
+    ref = reference(cell.conf, root)
     import jax
     devs = chips(cell.entry["chips"]) if need_chip else jax.devices()
     dev = devs[0]
@@ -300,8 +318,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
 
     # -- set-up: weights, packing, engine, every shape the traffic uses
     conf = cell.conf
-    params = system.pack(conf, seed)
-    eng = system.engine(conf, params, cell.cell["engine"], seed)
+    params = system.pack(conf, seed, root)
+    eng = system.engine(conf, params, cell.cell["engine"], seed, root)
     del params
     warm = warm_requests(cell)
     for req in warm:
@@ -342,12 +360,12 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     recs = gen.in_window()
     failed = [r for r in recs if not r.done]
     log_latency(gen, recs)
-    stats, control_stats = check(cell, seed, recs, control=control)
+    stats, control_stats = check(cell, seed, recs, ref, control=control)
     checks, correct = verdict(cell, stats, len(failed))
 
     ctx = Context(cell=cell, gen=gen, recs=recs, seconds=seconds,
                   setup_s=setup_s, counters=counters, peaks=pk,
-                  dims=costs.Dims.from_config(conf), trace=None)
+                  dims=system.dims(conf, root), trace=None)
     result = {"correct": bool(correct), "attempted": len(recs),
               "failed": len(failed)}
     if trace:
@@ -360,7 +378,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
         wanted = cell.end_to_end
     metrics = {}
     for m in wanted:
-        value = reader(m["name"], checkout / "chipbench")(ctx)
+        value = reader(m["name"], root)(ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     result["metrics"] = metrics
@@ -407,7 +425,7 @@ class Context:
     setup_s: float
     counters: dict
     peaks: dict | None
-    dims: costs.Dims
+    dims: object         # the architecture module's ``dims(conf)``
     trace: object
     #: host seconds (start, stop) of the profiler's span in a traced run
     traced: tuple | None = None
@@ -435,14 +453,15 @@ def traced_prefills(ctx: Context) -> list[int]:
             if r.turns and lo <= r.turns[0] < hi]
 
 
-def check(cell: Cell, seed: int, recs: list[Rec], *, control: bool = False):
-    """Gaps of the served tokens, over a seeded sample of finished requests
-    that holds the one with the most served tokens: the widest (``max``)
-    and the mean over every sampled token (None where nothing finished).
-    With ``control``, the same of the tokens the control (the reference
-    with float8 matmul inputs) puts first at the same positions.  Returns
-    ``(stats, control stats or None)``."""
-    from . import reference
+def check(cell: Cell, seed: int, recs: list[Rec], reference, *,
+          control: bool = False):
+    """Gaps of the served tokens against the configuration's ``reference``
+    module, over a seeded sample of finished requests that holds the one
+    with the most served tokens: the widest (``max``) and the mean over
+    every sampled token (None where nothing finished).  With ``control``,
+    the same of the tokens the control (the reference with float8 matmul
+    inputs) puts first at the same positions.  Returns ``(stats, control
+    stats or None)``."""
     t0 = time.perf_counter()
     done = [r for r in recs if r.done]
     if not done:

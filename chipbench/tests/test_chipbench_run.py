@@ -2,11 +2,14 @@
 its look for a chip skipped, and the real command without a chip.
 
 The tiny cell lives in a temporary checkout, added the way a later change
-adds one: a configuration, a traffic mix, a cell file, a metric reader and
-their entries in ``BENCHMARK.json``.  The engine runs the program's XLA
-path (``qimpl: xla``); what these runs check is the harness, the traffic
-load and the comparison that decides ``correct``, not speed.
+adds one: a configuration, a traffic mix, a cell file, a metric reader,
+an architecture module, a reference module and their entries in
+``BENCHMARK.json``.  The engine runs the program's XLA path (``qimpl:
+xla``); what these runs check is the harness, the traffic load and the
+comparison that decides ``correct``, not speed.
 """
+import dataclasses
+import hashlib
 import json
 import os
 import shutil
@@ -14,9 +17,11 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
+import numpy as np
 import pytest
 
-from chipbench import harness
+from chipbench import harness, system
 
 REPO = Path(__file__).resolve().parents[2]
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
@@ -34,6 +39,66 @@ NEW_METRIC = '''"""Requests that produced every token they asked for."""
 def read(ctx):
     return sum(r.done for r in ctx.recs)
 '''
+NEW_ARCH = '''"""A dense decoder under another name: the dense module does
+the work, and every call is recorded."""
+from pathlib import Path
+
+from .. import system
+
+DENSE = system.architecture("dense_decoder", Path(__file__).parents[1])
+CALLS = []
+
+
+def _dense(conf):
+    return dict(conf, architecture="dense_decoder")
+
+
+def arch(conf):
+    CALLS.append("arch")
+    return DENSE.arch(_dense(conf))
+
+
+def pack(conf, seed):
+    CALLS.append("pack")
+    return DENSE.pack(_dense(conf), seed)
+
+
+def dims(conf):
+    CALLS.append("dims")
+    return DENSE.dims(conf)
+'''
+NEW_REFERENCE = '''"""The dense reference under another name, every call
+recorded."""
+from . import reference
+
+CALLS = []
+
+
+class Reference(reference.Reference):
+    def __init__(self, conf, seed, **kw):
+        CALLS.append("init")
+        super().__init__(conf, seed, **kw)
+
+    def gaps(self, requests, control=False):
+        CALLS.append("gaps")
+        return super().gaps(requests, control=control)
+'''
+#: read from the tiny configuration at seed ``SEED`` on the CPU by the code
+#: before architectures and references were found by name: a digest of
+#: every leaf ``system.pack`` gave, the reference's gaps of ``SERVED``
+#: after ``PROMPT`` and its control's, and the dims the costs counted
+SEED = 2**40 + 3
+PACK_DIGEST = (25, "8c3bae47b4d26f4a34b2629bc0e46a955"
+                   "b7fd453dbf41c4a5a1b7c0816a3c4fe")
+PROMPT = [(7 * i + 3) % 500 for i in range(21)]
+SERVED = [11, 499, 0, 250, 37, 123]
+REF_GAPS = [2.89536452293396, 3.96569561958313, 2.8853700160980225,
+            1.9965916872024536, 2.3897926807403564, 2.355656623840332]
+REF_CONTROL_GAPS = [0.0, 0.0, 0.0, 0.0, 0.04233813285827637, 0.0]
+DIMS = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
+        "head_dim": 32, "d_ff": 256, "vocab_rows": 512, "layer_bits": (8, 4),
+        "head_bits": 8, "embed_bits": 8, "kv_bits": (4, 4), "kv_block": 16,
+        "act_bytes": 2}
 
 
 @pytest.fixture(scope="module")
@@ -44,9 +109,19 @@ def checkout(tmp_path_factory):
         (cb / sub).mkdir(parents=True)
     shutil.copytree(REPO / "chipbench" / "metrics", cb / "metrics")
     (cb / "metrics" / "tiny.requests_done.py").write_text(NEW_METRIC)
+    shutil.copytree(REPO / "chipbench" / "architectures", cb / "architectures",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (cb / "architectures" / "tiny_arch.py").write_text(NEW_ARCH)
+    shutil.copy(REPO / "chipbench" / "reference.py", cb)
+    (cb / "tiny_ref.py").write_text(NEW_REFERENCE)
     base = json.loads((REPO / "chipbench/configs/yi-6b.json").read_text())
-    for name, qimpl in (("tiny", "xla"), ("tinyi", "interpret")):
-        conf = dict(base, name=name, **TINY)
+    for name, qimpl, named in (
+            ("tiny", "xla", {}), ("tinyi", "interpret", {}),
+            ("tinyx", "xla", {"architecture": "tiny_arch",
+                              "reference": "tiny_ref"}),
+            ("tiny-noarch", "xla", {"architecture": "no_such_arch"}),
+            ("tiny-noref", "xla", {"reference": "no_such_ref"})):
+        conf = dict(base, name=name, **TINY, **named)
         conf["serving"] = dict(base["serving"], qimpl=qimpl,
                                vocab_rows=512, weight_bits={
                                    "embed": 8, "lm_head": 8, "layers": [8, 4]})
@@ -65,7 +140,10 @@ def checkout(tmp_path_factory):
               "batch_admission": False}
     for cell, conf, mix in (("tiny-open", "tiny", "tiny-open"),
                             ("tiny-closed", "tiny", "tiny-closed"),
-                            ("tinyi-open", "tinyi", "tiny-open")):
+                            ("tinyi-open", "tinyi", "tiny-open"),
+                            ("tinyx-open", "tinyx", "tiny-open"),
+                            ("tiny-noarch-open", "tiny-noarch", "tiny-open"),
+                            ("tiny-noref-open", "tiny-noref", "tiny-open")):
         (cb / "workloads" / f"{cell}.json").write_text(json.dumps({
             "config": conf, "traffic": mix, "engine": engine,
             "check": {"sample": 8, **TINY_LIMITS},
@@ -137,6 +215,62 @@ def test_control_fails_the_limit(checkout):
         assert r["gaps"][stat] == r["checks"][key]["value"]
         assert r["control_gaps"][stat] > r["checks"][key]["limit"]
     assert r["control_correct"] is False
+
+
+def test_dense_pack_unchanged(checkout):
+    conf = harness.load("configs", "tiny", checkout / "chipbench")
+    tree = system.pack(conf, SEED, checkout / "chipbench")
+    h = hashlib.sha256()
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    for path, leaf in leaves:
+        a = np.asarray(leaf)
+        for part in (jax.tree_util.keystr(path), str(a.dtype), str(a.shape)):
+            h.update(part.encode())
+        h.update(a.tobytes())
+    assert (len(leaves), h.hexdigest()) == PACK_DIGEST
+
+
+def test_dense_reference_unchanged(checkout):
+    conf = harness.load("configs", "tiny", checkout / "chipbench")
+    mod = harness.reference(conf, checkout / "chipbench")
+    ref = mod.Reference(conf, SEED, max_seq=96, max_out=24)
+    (gaps, control), = ref.gaps([(PROMPT, SERVED)], control=True)
+    assert gaps.tolist() == REF_GAPS and control.tolist() == REF_CONTROL_GAPS
+
+
+def test_dense_dims_unchanged(checkout):
+    conf = harness.load("configs", "tiny", checkout / "chipbench")
+    dims = system.dims(conf, checkout / "chipbench")
+    assert dataclasses.asdict(dims) == DIMS
+
+
+def test_new_architecture_and_reference_by_files_alone(checkout):
+    """A configuration that names an architecture module and a reference
+    module found only in the checkout runs end to end through both."""
+    cb = checkout / "chipbench"
+    r = run(checkout, "tinyx-open", seconds=1.0)
+    assert r["correct"] is True and r["failed"] == 0
+    assert r["checks"]["served_logit_gap_mean"]["value"] is not None
+    assert set(system.architecture("tiny_arch", cb).CALLS) == {
+        "arch", "pack", "dims"}
+    assert harness.reference({"reference": "tiny_ref"}, cb).CALLS == [
+        "init", "gaps"]
+
+
+@pytest.mark.parametrize("cell, missing", [
+    ("tiny-noarch-open", "architectures/no_such_arch.py"),
+    ("tiny-noref-open", "no_such_ref.py")])
+def test_name_with_no_file_fails_before_set_up(checkout, monkeypatch, cell,
+                                               missing):
+    from chipbench import weights
+
+    def drawn(*_a, **_k):
+        raise AssertionError("weights drawn")
+    monkeypatch.setattr(weights, "top", drawn)
+    monkeypatch.setattr(weights, "layer", drawn)
+    with pytest.raises(FileNotFoundError) as e:
+        run(checkout, cell)
+    assert str(checkout / "chipbench" / missing) in str(e.value)
 
 
 def _sample_plus_one(orig):
