@@ -7,8 +7,10 @@ traffic mix (``traffic/<name>.json``); ``workloads/<cell>.json`` holds the
 engine settings and the check's limit; every metric is read by
 ``metrics/<metric>.py``.  The configuration names its architecture
 (``architectures/<architecture>.py``, reached through ``system``) and its
-plain reference (``<reference>.py``).  Adding a cell, configuration,
-architecture, reference, mix or metric is adding files and entries.
+plain reference (``<reference>.py``).  A traced run counts each device-op
+family's operations and bytes with ``op_costs/<family>.py``.  Adding a
+cell, configuration, architecture, reference, mix, metric or kernel cost
+is adding files and entries.
 
 A reference module exports ``Reference(conf, seed, *, max_seq,
 max_out)`` whose ``gaps(requests, control=False)`` gives, for each
@@ -93,6 +95,13 @@ def reader(name: str, root: Path = ROOT):
     return system.module(
         root / "metrics" / f"{name}.py",
         "chipbench_metric_" + name.replace(".", "_").replace("-", "_")).read
+
+
+def op_costs(root: Path = ROOT) -> dict:
+    """Device-op family -> ``cost(op_name) -> (flops, bytes)``, from every
+    ``op_costs/<family>.py`` under ``root``."""
+    return {p.stem: system.module(p, f"chipbench.op_costs.{p.stem}").cost
+            for p in sorted((root / "op_costs").glob("*.py"))}
 
 
 def reference(conf: dict, root: Path = ROOT):
@@ -306,6 +315,7 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     # here, before anything is set up
     system.architecture(cell.conf["architecture"], root)
     ref = reference(cell.conf, root)
+    costs = op_costs(root) if trace else None
     import jax
     devs = chips(cell.entry["chips"]) if need_chip else jax.devices()
     dev = devs[0]
@@ -332,9 +342,9 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
 
     # -- the window
     tracer = system.tracer()
-    tr = _TracePlan(cell, seconds, tracer, pk) if trace else None
+    tr = _TracePlan(cell, seconds, tracer, pk, costs) if trace else None
     gen = LoadGen(eng, cell, seed, seconds, trace=tr)
-    before = eng.metrics.counter("decode_steps").value
+    before = system.counters(eng)
     gc.collect()
     gc.freeze()
     counter.on = True
@@ -342,8 +352,8 @@ def run(name: str, seed: int, seconds: float, trace: bool, *,
     jax.block_until_ready(eng.state)
     counter.on = False
     gc.unfreeze()
-    counters = {"decode_steps":
-                eng.metrics.counter("decode_steps").value - before}
+    counters = {name: value - before.get(name, 0.0)
+                for name, value in system.counters(eng).items()}
     log(f"window {seconds} s: {len(gen.in_window())} requests, "
         f"compilations inside the window: {counter.compiles} "
         f"(lowerings {counter.lowerings})")
@@ -423,6 +433,7 @@ class Context:
     recs: list
     seconds: float
     setup_s: float
+    #: every engine counter's growth over the window and its drain
     counters: dict
     peaks: dict | None
     dims: object         # the architecture module's ``dims(conf)``
@@ -498,8 +509,9 @@ class _TracePlan:
     turn boundary means every program dispatched in between ran inside the
     trace: the engine waits for its decode step at the end of each turn."""
 
-    def __init__(self, cell: Cell, seconds: float, tracer, peaks: dict):
-        self.cell, self.peaks = cell, peaks
+    def __init__(self, cell: Cell, seconds: float, tracer, peaks: dict,
+                 op_costs: dict):
+        self.cell, self.peaks, self.op_costs = cell, peaks, op_costs
         self.span = min(cell.cell["trace_seconds"], seconds)
         # the middle of the window, past the ramp from an empty engine
         self.begin = (seconds - self.span) / 2
@@ -560,6 +572,7 @@ class _TracePlan:
         self.tracer.disable()
         try:
             return trace_reduce.reduce_dir(self.dir, peaks=self.peaks,
+                                           op_costs=self.op_costs,
                                            spans=spans,
                                            sync_host_s=self.sync,
                                            window=tuple(self.host),

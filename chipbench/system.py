@@ -84,6 +84,13 @@ def engine(conf: dict, params, settings: dict, seed: int, root: Path = ROOT):
         state_bits=s["kv_bits"][0], kv_block=s["kv_block"], seed=seed % 2**31)
 
 
+def counters(eng) -> dict[str, float]:
+    """The value of every counter in the engine's metrics registry."""
+    from repro.obs.metrics import Counter
+    return {name: m.value for name, m in eng.metrics.items()
+            if isinstance(m, Counter)}
+
+
 def request(uid: int, prompt: list[int], max_new: int):
     return program()[4].Request(uid=uid, prompt=prompt, max_new_tokens=max_new)
 

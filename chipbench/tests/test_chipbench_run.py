@@ -39,6 +39,15 @@ NEW_METRIC = '''"""Requests that produced every token they asked for."""
 def read(ctx):
     return sum(r.done for r in ctx.recs)
 '''
+COUNTER_METRIC = '''"""Prompt tokens the engine prefilled over the window
+and its drain, by its own counter; each context read is kept."""
+CTXS = []
+
+
+def read(ctx):
+    CTXS.append(ctx)
+    return ctx.counters["prefill_tokens"]
+'''
 NEW_ARCH = '''"""A dense decoder under another name: the dense module does
 the work, and every call is recorded."""
 from pathlib import Path
@@ -109,6 +118,7 @@ def checkout(tmp_path_factory):
         (cb / sub).mkdir(parents=True)
     shutil.copytree(REPO / "chipbench" / "metrics", cb / "metrics")
     (cb / "metrics" / "tiny.requests_done.py").write_text(NEW_METRIC)
+    (cb / "metrics" / "tiny.prefill_tokens.py").write_text(COUNTER_METRIC)
     shutil.copytree(REPO / "chipbench" / "architectures", cb / "architectures",
                     ignore=shutil.ignore_patterns("__pycache__"))
     (cb / "architectures" / "tiny_arch.py").write_text(NEW_ARCH)
@@ -142,6 +152,7 @@ def checkout(tmp_path_factory):
                             ("tiny-closed", "tiny", "tiny-closed"),
                             ("tinyi-open", "tinyi", "tiny-open"),
                             ("tinyx-open", "tinyx", "tiny-open"),
+                            ("tiny-counted", "tiny", "tiny-open"),
                             ("tiny-noarch-open", "tiny-noarch", "tiny-open"),
                             ("tiny-noref-open", "tiny-noref", "tiny-open")):
         (cb / "workloads" / f"{cell}.json").write_text(json.dumps({
@@ -157,6 +168,11 @@ def checkout(tmp_path_factory):
                                 "better": "higher", "bound": 0.01,
                                 "source": "host_clock",
                                 "workloads": ["tiny-closed"]})
+    bench["end_to_end"].append({"name": "tiny.prefill_tokens",
+                                "unit": "tokens", "better": "higher",
+                                "bound": 0.01,
+                                "source": "host_clock",
+                                "workloads": ["tiny-counted"]})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     return root
 
@@ -255,6 +271,25 @@ def test_new_architecture_and_reference_by_files_alone(checkout):
         "arch", "pack", "dims"}
     assert harness.reference({"reference": "tiny_ref"}, cb).CALLS == [
         "init", "gaps"]
+
+
+def test_readers_see_every_engine_counter(checkout):
+    """A reader added as a file reads any counter of the engine's registry,
+    as its growth between the snapshots after warm-up and after the drain."""
+    path = checkout / "chipbench" / "metrics" / "tiny.prefill_tokens.py"
+    r = run(checkout, "tiny-counted", seed=2**35 + 9)
+    assert r["failed"] == 0
+    (ctx,) = system.module(path, "counted").CTXS
+    recs = ctx.gen.recs.values()
+    # every request of the window was admitted between the snapshots, its
+    # prompt but the last token prefilled (the decode step replays that)
+    heads = sum(len(rec.req.prompt) - 1 for rec in recs)
+    assert r["metrics"]["tiny.prefill_tokens"]["value"] == heads > 0
+    assert ctx.counters["completed"] == len(recs) == r["attempted"]
+    # decode_steps as before: one decode step a turn that committed tokens
+    assert ctx.counters["decode_steps"] == len(
+        {t for rec in recs for t in rec.turns})
+    assert {"loop_turns", "preemptions", "wall_s"} <= set(ctx.counters)
 
 
 @pytest.mark.parametrize("cell, missing", [

@@ -9,9 +9,15 @@ operations (``XLA Ops``), each named by its HLO text:
 ..., s8[5120,4096] ..., f32[1,5120] ...)``.  A Pallas kernel's operation
 is named after the jitted wrapper that made it, so the kernel families
 are ``quant_matmul_pallas``, ``quant_gemv_pallas`` and
-``quant_kv_decode_step_pallas``; the shapes in the text give each
-weight call's operations and bytes.  An operation belongs to the program
-whose run contains its start.
+``quant_kv_decode_step_pallas`` (a call vmapped over stacked weights, as
+an expert GEMM is, is named ``vmap_jit_quant_matmul_pallas__``).  An
+operation belongs to the program whose run contains its start.
+
+A family's operations and bytes come from ``op_costs/<family>.py`` in the
+checkout's ``chipbench/``: its ``cost(op_name) -> (flops, bytes)`` reads
+them from the op's HLO text alone.  The harness hands the reduction those
+functions by family (``harness.op_costs``), so a kernel added to the
+program is counted by adding a file.
 
 Host spans come from the program's tracer on ``time.perf_counter``; a
 ``chipbench.sync`` annotation, written to the profiler at a known
@@ -27,6 +33,7 @@ import bisect
 import dataclasses
 import glob
 import json
+import math
 import re
 from collections import defaultdict
 
@@ -97,13 +104,26 @@ def shapes(op_name: str) -> list[tuple[str, tuple[int, ...]]]:
 
 def weight_call_cost(op_name: str) -> tuple[float, float]:
     """(flops, bytes) of one packed-weight GEMM/GEMV call, from its HLO
-    shapes: ``out (M, N)``, ``x (lanes, M, K / lanes)``, ``w (N, K_packed)``,
-    ``scale (1, N)``."""
-    (ot, (m, n)), (xt, (lanes, _, kl)), (_, (_, kp)), _scale = \
-        shapes(op_name)[:4]
+    shapes: ``out (*B, M, N)``, ``x (*B, lanes, M, K / lanes)``,
+    ``w (*B, N, K_packed)``, ``scale (*B, 1, N)``.  Leading dims ``B`` are
+    a call over stacked weights (a vmapped expert GEMM): it does the 2-D
+    call's work once per stacked matrix."""
+    got = shapes(op_name)[:4]
+    if len(got) < 4:
+        raise ValueError(f"not a weight call (out, x, w, scale): {op_name}")
+    (ot, out), (xt, x), (_, w), (_, scale) = got
+    lead = out[:-2]
+    nb = len(lead)
+    if len(out) < 2 or len(x) != nb + 3 or len(w) != nb + 2 \
+            or len(scale) != nb + 2 \
+            or not lead == x[:nb] == w[:nb] == scale[:nb]:
+        raise ValueError(f"weight call shapes out {out}, x {x}, w {w}, "
+                         f"scale {scale} share no leading dims: {op_name}")
+    (m, n), (lanes, _, kl), kp = out[nb:], x[nb:], w[-1]
     k = lanes * kl
     nbytes = (n * kp + 4 * n + _BYTES[xt] * m * k + _BYTES[ot] * m * n)
-    return 2.0 * m * k * n, float(nbytes)
+    stacked = math.prod(lead)
+    return stacked * 2.0 * m * k * n, float(stacked * nbytes)
 
 
 def _union(intervals) -> list[tuple[int, int]]:
@@ -123,7 +143,9 @@ class Reduced:
     turn_range: tuple     # engine turns [first, stop) inside the trace
     module_s: dict        # program -> device seconds
     module_n: dict        # program -> runs
-    kernels: dict         # (program, family) -> {"time_s", "roofline_s", "calls"}
+    #: (program, family) -> {"time_s", "roofline_s", "calls"}; roofline_s
+    #: sums the family's cost file over its calls, 0.0 where it has none
+    kernels: dict
     phase_s: dict         # engine phase -> host seconds in the window
     breakdown: dict
     notes: list
@@ -137,10 +159,11 @@ class Reduced:
         return 100.0 * (1.0 - self.busy_s / self.window_s)
 
 
-def reduce(trace: Trace, *, peaks: dict, spans=(), sync_host_s=None,
-           window=None, turns=(0, 0)) -> Reduced:
+def reduce(trace: Trace, *, peaks: dict, op_costs: dict, spans=(),
+           sync_host_s=None, window=None, turns=(0, 0)) -> Reduced:
     """The trace of a window of host seconds ``window`` = (start, stop),
-    covering engine turns ``turns`` = [first, stop)."""
+    covering engine turns ``turns`` = [first, stop).  ``op_costs`` maps an
+    op family to its ``cost(op_name) -> (flops, bytes)``."""
     t_lo = trace.marks.get(SYNC)
     if t_lo is None:       # no sync mark: the trace's own extent
         t_lo = min((o[1] for o in trace.ops), default=0)
@@ -165,14 +188,17 @@ def reduce(trace: Trace, *, peaks: dict, spans=(), sync_host_s=None,
         prog = owner(s) or "?"
         base = op_base(name)
         by_op[f"{prog}/{base}"] += d / 1e9
-        if base not in (GEMM, GEMV, KV):
+        # a Pallas kernel (named after its jitted wrapper, ``..._pallas``,
+        # ``..._pallas__`` under vmap) or a family with a cost file
+        if not (base.rstrip("_").endswith("_pallas") or base in op_costs):
             continue
         k = kernels.setdefault((prog, base), {"time_s": 0.0, "roofline_s": 0.0,
                                               "calls": 0})
         k["time_s"] += d / 1e9
         k["calls"] += 1
-        if base != KV:
-            k["roofline_s"] += roofline_s(*weight_call_cost(name), peaks)[0]
+        if base in op_costs:
+            k["roofline_s"] += roofline_s(*_cost(op_costs[base], base, name),
+                                          peaks)[0]
     busy = _union((max(s, t_lo), min(s + d, t_hi)) for _, s, d in trace.ops
                   if s + d > t_lo and s < t_hi)
     busy_s = sum(e - s for s, e in busy) / 1e9
@@ -205,6 +231,17 @@ def reduce(trace: Trace, *, peaks: dict, spans=(), sync_host_s=None,
                    turn_range=tuple(turns), module_s=dict(module_s),
                    module_n=dict(module_n), kernels=kernels,
                    phase_s=dict(phase_s), breakdown=breakdown, notes=notes)
+
+
+def _cost(cost, family: str, op_name: str) -> tuple[float, float]:
+    """``cost(op_name)``.  An op it cannot read fails the reduction: left
+    out, its calls' time would still count and the share read low unseen."""
+    try:
+        flops, nbytes = cost(op_name)
+    except Exception as e:
+        raise ValueError(f"op_costs/{family}.py cannot read the op "
+                         f"{op_name!r}: {e!r}") from e
+    return flops, nbytes
 
 
 def _phase_at(phase_iv, t: float) -> str:
