@@ -33,7 +33,7 @@ from repro.kernels.quant_gemv.ref import quant_gemv_ref
 from repro.kernels.quant_kv import ops as kv_ops
 from repro.kernels.quant_kv.ref import (quant_kv_append_ref,
                                         quant_kv_attention_ref)
-from repro.kernels.quant_matmul.ops import quant_matmul
+from repro.kernels.quant_matmul.ops import qt_matmul, quant_matmul
 from repro.kernels.quant_matmul.ref import quant_matmul_ref
 from repro.kvcache import paged as pg
 from repro.kvcache.cache import init_kv_layer, insert_rows
@@ -100,9 +100,12 @@ KV_VALID = jnp.arange(S)[None, :] < jnp.asarray(LENS)[:, None]
 
 
 def _run_quant_matmul(impl, bits):
-    # (48, 256, 128): one k block; (130, 512, 128): the kernel's cross-k-block
-    # accumulation loop AND the M tail mask across multiple M blocks
-    for m, k, n in ((48, 256, 128), (130, 512, 128)):
+    # (48, 256, 128): one k block; (130, 512, 128): an M tail in a padded
+    # block; (600, 256, 128): the M tail across two M blocks of 320 rows;
+    # K/lanes = 2944 = 128 x 23: a whole-K block walked in 23 sub-slices
+    lanes = LANES[bits]
+    for m, k, n in ((48, 256, 128), (130, 512, 128), (600, 256, 128),
+                    (40, 2944 * lanes, 256)):
         key = jax.random.key(bits * 1000 + m)
         w = jax.random.normal(jax.random.fold_in(key, 0), (k, n)) * 0.05
         x = jax.random.normal(jax.random.fold_in(key, 1), (m, k))
@@ -111,6 +114,16 @@ def _run_quant_matmul(impl, bits):
         out = quant_matmul(x, qt.packed, scale, bits, qt.k, impl=impl)
         ref = quant_matmul_ref(x, qt.packed, scale, bits, qt.k)
         assert _rel(out, ref) <= 1e-4, (m, k, n)
+    # a stacked (expert) weight: the kernel vmapped over the leading dim
+    key = jax.random.key(bits * 1000 + 7)
+    w = jax.random.normal(jax.random.fold_in(key, 0), (3, 256, 384)) * 0.05
+    x = jax.random.normal(jax.random.fold_in(key, 1), (3, 24, 256))
+    qt = quantize_tensor(w, bits)
+    out = qt_matmul(x, qt, impl=impl)
+    for e in range(3):
+        ref = quant_matmul_ref(x[e], qt.packed[e], qt.scale[e].reshape(1, -1),
+                               bits, qt.k)
+        assert _rel(out[e], ref) <= 1e-4, e
 
 
 def _run_quant_gemv(impl, bits):

@@ -1,4 +1,4 @@
-"""The main-path Pallas kernels compile for a TPU v5e at Yi-6B widths.
+"""The main-path Pallas kernels compile for a TPU v5e at the cells' widths.
 
 No chip is attached: the TPU compiler compiles for a described ``v5e:2x2``
 topology, one ``SingleDeviceSharding`` on its first device, from shapes
@@ -22,8 +22,10 @@ from repro.kernels.quant_kv.kernel import (quant_kv_decode_step_paged_pallas,
                                            quant_kv_decode_step_pallas)
 from repro.kernels.quant_matmul.kernel import quant_matmul_pallas
 
-# Yi-6B: d_model 4096, d_ff 11008, 32 query / 4 KV heads of 128
-D, FF, NQ, NKV, HD = 4096, 11_008, 32, 4, 128
+# Yi-6B: d_model 4096, d_ff 11008, 32 query / 4 KV heads of 128, vocab 64000
+D, FF, NQ, NKV, HD, VOCAB = 4096, 11_008, 32, 4, 128, 64_000
+# Phi-3-medium: d_model 5120, d_ff 17920 (gate and up fused: N 35840)
+PHI_D, PHI_FF = 5120, 17_920
 SLOTS, SEQ, BLOCK = 4, 2048, 16
 
 
@@ -88,6 +90,13 @@ CASES = {
     "gemv_wqkv_4bit": lambda: _gemv(4, (NQ + 2 * NKV) * HD, D),
     "gemv_wdown_2bit": lambda: _gemv(2, D, FF),
     "gemm_wdown_m128_4bit": lambda: _gemm(4, 128, D, FF),
+    # the cells' calls whose chosen blocks fill the most VMEM
+    "gemm_phi3_gate_up_m2048_4bit": lambda: _gemm(4, 2048, 2 * PHI_FF, PHI_D),
+    "gemm_phi3_gate_up_m2048_8bit": lambda: _gemm(8, 2048, 2 * PHI_FF, PHI_D),
+    "gemm_phi3_down_m2048_4bit": lambda: _gemm(4, 2048, PHI_D, PHI_FF),
+    "gemm_phi3_down_m2048_8bit": lambda: _gemm(8, 2048, PHI_D, PHI_FF),
+    "gemm_wdown_m32_4bit": lambda: _gemm(4, 32, D, FF),
+    "gemm_lm_head_m32_8bit": lambda: _gemm(8, 32, VOCAB, D),
     "decode_step_dense_4bit": lambda: _step(4, paged=False),
     "decode_step_dense_8bit": lambda: _step(8, paged=False),
     "decode_step_paged_4bit": lambda: _step(4, paged=True),
