@@ -21,9 +21,9 @@ import traceback
 
 from repro.launch.compile_cache import enable_compile_cache
 
-from . import (allocator, calibration, decode_step, decode_throughput,
-               degradation, fig3_trajectory, fig5_hw, kvcache, kvcache_paged,
-               latency, roofline, speculative, table1_sigma_kl, table2_phases,
+from . import (allocator, calibration, decode_throughput, degradation,
+               fig3_trajectory, fig5_hw, kvcache, kvcache_paged, latency,
+               roofline, speculative, table1_sigma_kl, table2_phases,
                table3_sota, table4_hparam, table5_bops, table6_mac)
 
 SECTIONS = {
@@ -33,8 +33,6 @@ SECTIONS = {
     "kvcache_paged": ("Paged KV cache: allocated vs dense state bytes, pool "
                       "utilization (BENCH_kvcache_paged.json)",
                       kvcache_paged.run),
-    "decode_step": ("Fused decode step: kernel time vs serve-loop overhead "
-                    "(BENCH_decode_step.json)", decode_step.run),
     "speculative": ("Self-speculative decoding: acceptance + tokens/s vs "
                     "non-speculative (BENCH_speculative.json)",
                     speculative.run),
@@ -72,10 +70,6 @@ HEADLINES = {
     "BENCH_kvcache_paged.json": [("state_bytes.reduction_vs_dense_x", "higher"),
                                  ("pool.utilization", "higher"),
                                  ("tokens_per_s_ratio", "higher")],
-    "BENCH_decode_step.json": [("engine.tokens_per_s", "higher"),
-                               ("kernel.dense.micros", "lower"),
-                               ("overhead.fraction_of_step", "lower"),
-                               ("phases.attributed_fraction", "higher")],
     # open-loop wall-clock percentiles are tracked headlines; the compare
     # GATE rides on the mixed-workload cell — its headline p99 and the
     # (machine-speed cancelling) improvement ratio are what chunked
@@ -119,12 +113,6 @@ REGRESSION_TOLERANCE = 0.10
 INFORMATIONAL = {
     "BENCH_latency.json": {"ttft.p50_s", "ttft.p99_s",
                            "itl.p50_s", "itl.p99_s"},
-    # BENCH_decode_step.json is now in the CI compare loop: its GATE is the
-    # phase-attribution fraction (dimensionless, machine-independent); the
-    # raw throughput / kernel-micros headlines track the CI machine and
-    # stay report-only
-    "BENCH_decode_step.json": {"engine.tokens_per_s", "kernel.dense.micros",
-                               "overhead.fraction_of_step"},
 }
 
 

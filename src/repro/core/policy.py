@@ -399,9 +399,8 @@ def classify_zone(acc: float, res, t: "Targets | Budget") -> Zone:
 ARTIFACT_VERSION = 6
 
 #: versions this build can still read (v1 artifacts have no KV policy,
-#: v1/v2 have no paged pool geometry, v1-v3 have no draft policy, v1-v4
-#: have no kernel configs, v1-v5 have no provenance — all load with those
-#: fields None/0)
+#: v1/v2 have no paged pool geometry, v1-v3 have no draft policy, v1-v5
+#: have no provenance — all load with those fields None/0)
 READABLE_ARTIFACT_VERSIONS = (1, 2, 3, 4, 5, 6)
 
 
@@ -474,15 +473,6 @@ class PolicyArtifact:
                    propose tokens.  None: no speculation.
     draft_k        tokens the draft proposes per verify step (> 0 iff
                    ``draft_policy`` is set) — the searched burst length.
-    kernel_configs autotuned fused decode-step kernel configs (v5,
-                   DESIGN.md §15): a list of ``{"key", "config", "micros",
-                   "candidates"}`` entries keyed on (family, k_bits,
-                   v_bits, heads, head_dim, block, impl), produced by
-                   ``kernels.autotune.autotune_state``.  The engine
-                   installs them at deploy so serving replays the searched
-                   layouts instead of re-timing.  None: dispatcher
-                   defaults.  Every candidate is bitwise-equivalent, so a
-                   stale table can cost speed but never correctness.
     provenance     how the search arrived at this policy (v6, DESIGN.md §18):
                    search config + limits, seed, per-phase iteration counts
                    and SearchReport digests, iteration history and per-layer
@@ -503,7 +493,6 @@ class PolicyArtifact:
     pool: dict | None = None
     draft_policy: BitPolicy | None = None
     draft_k: int = 0
-    kernel_configs: list | None = None
     provenance: dict | None = None
     meta: dict = dataclasses.field(default_factory=dict)
     version: int = ARTIFACT_VERSION
@@ -512,8 +501,7 @@ class PolicyArtifact:
     def build(cls, policy: BitPolicy, *, backend: str = "", report: Mapping | None = None,
               budget: Budget | None = None, state_policy: "BitPolicy | None" = None,
               pool: Mapping | None = None, draft_policy: "BitPolicy | None" = None,
-              draft_k: int = 0, kernel_configs: list | None = None,
-              provenance: Mapping | None = None,
+              draft_k: int = 0, provenance: Mapping | None = None,
               meta: Mapping | None = None) -> "PolicyArtifact":
         if pool is not None:
             if state_policy is None:
@@ -531,12 +519,6 @@ class PolicyArtifact:
                 != layer_registry_hash(policy.layers)):
             raise ValueError("draft_policy must cover the same weight "
                              "registry as the deployed policy")
-        if kernel_configs is not None:
-            for e in kernel_configs:
-                if not isinstance(e, Mapping) or {"key", "config"} - set(e):
-                    raise ValueError(
-                        "each kernel_configs entry needs 'key' and 'config' "
-                        f"(got {e!r})")
         if provenance is not None:
             validate_provenance(provenance)
         return cls(policy=policy, registry_hash=layer_registry_hash(policy.layers),
@@ -546,8 +528,6 @@ class PolicyArtifact:
                                         if state_policy is not None else ""),
                    pool=dict(pool) if pool is not None else None,
                    draft_policy=draft_policy, draft_k=int(draft_k),
-                   kernel_configs=(list(kernel_configs)
-                                   if kernel_configs is not None else None),
                    provenance=(dict(provenance)
                                if provenance is not None else None),
                    meta=dict(meta or {}))
@@ -587,7 +567,6 @@ class PolicyArtifact:
                 "draft_policy": (json.loads(self.draft_policy.to_json())
                                  if self.draft_policy is not None else None),
                 "draft_k": self.draft_k,
-                "kernel_configs": self.kernel_configs,
                 "provenance": self.provenance,
                 "meta": self.meta,
                 "policy": json.loads(self.policy.to_json()),
@@ -606,6 +585,8 @@ class PolicyArtifact:
         provenance = d.get("provenance")
         if provenance is not None:
             validate_provenance(provenance)
+        # a v5/v6 document may still carry "kernel_configs" (decode-step
+        # layouts, every one bitwise-equivalent): it is read past and dropped
         return cls(
             policy=BitPolicy.from_json(json.dumps(d["policy"])),
             registry_hash=d["registry_hash"],
@@ -618,8 +599,6 @@ class PolicyArtifact:
             draft_policy=(BitPolicy.from_json(json.dumps(d["draft_policy"]))
                           if d.get("draft_policy") else None),
             draft_k=int(d.get("draft_k", 0)),
-            kernel_configs=(list(d["kernel_configs"])
-                            if d.get("kernel_configs") else None),
             provenance=dict(provenance) if provenance is not None else None,
             meta=dict(d.get("meta") or {}),
             version=version)

@@ -18,8 +18,7 @@ head_dim, f32 scales per sequence block) and its paged twin:
   requantizes under a fresh scale.  The caller places the block back
   (ops.py).
 
-* **fused decode step** — append + attend in one kernel (dense, paged, and
-  with the Q/K/V projection pulled in).
+* **fused decode step** — append + attend in one kernel (dense and paged).
 
 Layout inside the kernels:
 
@@ -50,7 +49,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import LANES
 from repro.kernels.lanes import deinterleave, interleave, lane_planes, pack_planes
-from repro.kernels.quant_gemv.kernel import plane_dot
 
 _F32 = jnp.float32
 
@@ -638,131 +636,3 @@ def quant_kv_decode_step_paged_pallas(
         b=b, n_kv=n_kv, g=g, block=block, lv=lv, hk=hk, hv=hv,
         interpret=interpret)
 
-
-def _split_lanes(row: jax.Array, lanes: int) -> list[jax.Array]:
-    """(1, hd) row -> ``lanes`` (1, hd/lanes) planes, in-kernel.
-
-    One exact one-hot dot per plane (each output is a single input value
-    times 1.0 at f32 precision), since a strided lane gather has no cheap
-    vector form.
-    """
-    if lanes == 1:
-        return [row]
-    hd = row.shape[-1]
-    r = jax.lax.broadcasted_iota(jnp.int32, (hd, hd // lanes), 0)
-    c = jax.lax.broadcasted_iota(jnp.int32, (hd, hd // lanes), 1)
-    return [_dot(row, (r == c * lanes + lane).astype(_F32), ((1,), (0,)))
-            for lane in range(lanes)]
-
-
-def _fused_step_proj_kernel(pos_ref, x_ref, wq_ref, wqs_ref, wk_ref, wks_ref,
-                            wv_ref, wvs_ref, cos_ref, sin_ref, kp_ref, ks_ref,
-                            vp_ref, vs_ref, kt_ref, kts_ref, vt_ref, vts_ref,
-                            mask_ref, out_ref, kblk_ref, ksc_ref, vblk_ref,
-                            vsc_ref, qbuf, kbuf, vbuf, *, w_bits: int,
-                            k_bits: int, v_bits: int, g: int, hd: int,
-                            block: int):
-    pos = pos_ref[pl.program_id(0)]
-    bidx, off = pos // block, pos % block
-    x = x_ref[0]                                              # (lanes_w, 1, dp)
-    cos, sin = cos_ref[0], sin_ref[0]                         # (1, hd)
-
-    def proj(w_ref, ws_ref, h, rope: bool):
-        # quant_gemv's order: integer-level plane dots, then the scale
-        row = plane_dot(x, w_ref[h], w_bits) * ws_ref[h]      # (1, hd)
-        if rope:  # [x1, x2] -> [x1 cos - x2 sin, x2 cos + x1 sin]
-            row = row * cos + pltpu.roll(row, hd // 2, 1) * sin
-        return row
-
-    lk, lv = LANES[k_bits], LANES[v_bits]
-    for h in range(g):
-        planes = _split_lanes(proj(wq_ref, wqs_ref, h, True), lk)
-        for lane, plane in enumerate(planes):
-            qbuf[lane, h:h + 1, :] = plane
-    kbytes, kcol = _step_side(
-        _split_lanes(proj(wk_ref, wks_ref, 0, True), lk), kp_ref, ks_ref,
-        kt_ref, kts_ref, kblk_ref, ksc_ref, kbuf, bidx, off, bits=k_bits)
-    vbytes, vcol = _step_side(
-        _split_lanes(proj(wv_ref, wvs_ref, 0, False), lv), vp_ref, vs_ref,
-        vt_ref, vts_ref, vblk_ref, vsc_ref, vbuf, bidx, off, bits=v_bits)
-    _store_planes(out_ref, _attend(qbuf[...], kbytes, kcol, vbytes, vcol,
-                                   mask_ref[0], k_bits=k_bits, v_bits=v_bits,
-                                   hd=hd))
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "w_bits", "k_bits", "v_bits", "n_heads", "hd", "block", "interpret"))
-def quant_kv_decode_step_proj_pallas(
-    pos: jax.Array,       # (B,) int32 per-slot write positions
-    x: jax.Array,         # (B, d) float — post-norm hidden, one token/slot
-    w_packed: jax.Array,  # (N, d/lanes_w) int8 — fused wqkv, N = (nh+2*nkv)*hd
-    w_scale: jax.Array,   # (1, N) f32
-    cos: jax.Array,       # (B, hd/2) f32 — rope factors at pos
-    sin: jax.Array,
-    k_packed: jax.Array,  # (B, n_kv, S, hd/lanes_k) int8
-    k_scale: jax.Array,
-    v_packed: jax.Array,
-    v_scale: jax.Array,
-    mask: jax.Array,      # (B, S) f32 additive
-    *,
-    w_bits: int,
-    k_bits: int,
-    v_bits: int,
-    n_heads: int,
-    hd: int,
-    block: int,
-    interpret: bool = False,
-):
-    """Fused step with the Q/K/V projection pulled into the same dispatch.
-
-    Each (slot, kv-head) program DMAs only its slice of the fused ``wqkv``
-    buffer, viewed as ``(N/hd, hd, ·)`` head blocks — the query group's
-    ``g`` heads plus one K head and one V head — projects with the gemv
-    plane-dot + scale-after order, applies rope, and falls into the same
-    requant + attend body as the plain fused step.  Geometry gate (ops.py):
-    fused ``wqkv`` leaf, default rope, no qk-norm, single gemv K-step
-    (d <= 512).
-    """
-    b, d = x.shape
-    n_kv = k_packed.shape[1]
-    g = n_heads // n_kv
-    nb = k_packed.shape[2] // block
-    lw, lk, lv = LANES[w_bits], LANES[k_bits], LANES[v_bits]
-    hk, hv = k_packed.shape[-1], v_packed.shape[-1]
-    dp = w_packed.shape[-1]
-    n_rows = w_packed.shape[0] // hd
-    # wqkv head blocks: q group j = block j of g heads; K head j and V head
-    # j = single-head blocks n_heads + j and n_heads + n_kv + j
-    heads = lambda n, first, shape: pl.BlockSpec(
-        (n, *shape), lambda i, j, p: (first + j, 0, 0))
-    row = lambda shape: pl.BlockSpec((1, *shape), lambda i, j, p: (i, 0, 0))
-    # full-width rope rows: x*cos_f + roll(x, hd/2)*sin_f is apply_rope's
-    # [x1 cos - x2 sin, x2 cos + x1 sin]
-    cos_f = jnp.concatenate([cos, cos], axis=-1)[:, None, :]
-    sin_f = jnp.concatenate([-sin, sin], axis=-1)[:, None, :]
-    w3 = w_packed.reshape(n_rows, hd, dp)
-    s3 = w_scale.reshape(n_rows, 1, hd)
-    return _step_call(
-        functools.partial(_fused_step_proj_kernel, w_bits=w_bits,
-                          k_bits=k_bits, v_bits=v_bits, g=g, hd=hd,
-                          block=block),
-        (b, n_kv), 1,
-        [pl.BlockSpec((1, lw, 1, dp), lambda i, j, p: (i, 0, 0, 0)),
-         heads(g, 0, (hd, dp)), heads(g, 0, (1, hd)),
-         heads(1, n_heads, (hd, dp)), heads(1, n_heads, (1, hd)),
-         heads(1, n_heads + n_kv, (hd, dp)), heads(1, n_heads + n_kv, (1, hd)),
-         row((1, hd)), row((1, hd)),
-         *_dense_step_specs(nb, block, hk, hv),
-         _mask_spec(nb * block)],
-        [pltpu.VMEM((lk, g, hk), _F32),
-         pltpu.VMEM((nb, block, hk), jnp.int32),
-         pltpu.VMEM((nb, block, hv), jnp.int32)],
-        # the fused wqkv buffer + scale enter three times — the q-group,
-        # K-head and V-head specs each DMA their own head blocks
-        (jnp.asarray(pos, jnp.int32),
-         deinterleave(x.astype(_F32)[:, None, :], lw, 1),
-         w3, s3, w3, s3, w3, s3, cos_f, sin_f,
-         *_dense_step_args(k_packed, k_scale, v_packed, v_scale, block),
-         mask[:, None, :]),
-        b=b, n_kv=n_kv, g=g, block=block, lv=lv, hk=hk, hv=hv,
-        interpret=interpret)

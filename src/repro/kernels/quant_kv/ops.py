@@ -19,14 +19,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.kvcache.cache import QuantizedKVLayer
 from repro.kvcache.paged import PagedKVLayer, TRASH_BLOCK
 
 from .kernel import (quant_kv_append_paged_pallas, quant_kv_append_pallas,
                      quant_kv_attention_paged_pallas, quant_kv_attention_pallas,
                      quant_kv_decode_step_paged_pallas,
-                     quant_kv_decode_step_pallas,
-                     quant_kv_decode_step_proj_pallas)
+                     quant_kv_decode_step_pallas)
 from .ref import (quant_kv_append_paged_ref, quant_kv_append_ref,
                   quant_kv_attention_paged_ref, quant_kv_attention_ref,
                   quant_kv_decode_step_paged_ref, quant_kv_decode_step_ref)
@@ -162,16 +160,6 @@ def quant_kv_append(
                                v_packed=vp, v_scale=vsc)
 
 
-def _active_config(layer, paged: bool, impl: str) -> dict | None:
-    """Tuned layout for this geometry, if one is installed (trace-time)."""
-    from repro.kernels import autotune
-
-    b, s, n_kv, hd = layer.shape
-    return autotune.lookup(
-        "decode_step_paged" if paged else "decode_step", layer.k_bits,
-        layer.v_bits, n_kv, hd, layer.block, impl)
-
-
 def quant_kv_decode_step(
     q: jax.Array,                # (B, 1, hq, hd) or (B, hq, hd)
     layer,                       # QuantizedKVLayer | PagedKVLayer
@@ -182,28 +170,24 @@ def quant_kv_decode_step(
     *,
     impl: str = "auto",
     out_dtype=None,
-    config: dict | None = None,
 ):
     """ONE fused dispatch per layer per decode step: append + attend.
 
-    Bitwise-identical to ``quant_kv_append`` followed by
+    The one decode step of each cache container: the dense and the paged
+    Pallas kernels, with ``ref.quant_kv_decode_step{,_paged}_ref`` as the
+    XLA fallback.  Bitwise-identical to ``quant_kv_append`` followed by
     ``quant_kv_attention`` on every impl (the parity harness pins it); the
-    packed cache bytes are read once instead of once per op.  ``config``
-    picks a tuned data-movement layout (``kernels/autotune``); when None,
-    the process-wide table installed by ``autotune.set_active_configs`` is
-    consulted at trace time.  Returns ``(o, updated layer)`` with ``o``
-    shaped like ``q``.
+    packed cache bytes are read once instead of once per op.  Returns
+    ``(o, updated layer)`` with ``o`` shaped like ``q``.
     """
     impl = _resolve(impl)
     paged = isinstance(layer, PagedKVLayer)
     lead4 = q.ndim == 4
     q3 = q[:, 0] if lead4 else q                      # (B, hq, hd)
-    if config is None:
-        config = _active_config(layer, paged, impl)
     if impl == "xla":
         ref = quant_kv_decode_step_paged_ref if paged else quant_kv_decode_step_ref
         o, layer = ref(q3, layer, pos, k_new, v_new, kv_valid,
-                       out_dtype=out_dtype or q.dtype, config=config)
+                       out_dtype=out_dtype or q.dtype)
     elif impl in ("pallas", "interpret"):
         interp = impl == "interpret"
         b, s, n_kv, hd = layer.shape
@@ -244,57 +228,3 @@ def quant_kv_decode_step(
         raise ValueError(f"unknown impl {impl!r}")
     return (o[:, None] if lead4 else o), layer
 
-
-def can_fuse_qkv(layer, d_model: int, w_bits: int, impl: str) -> bool:
-    """Geometry gate for pulling the Q/K/V projection into the fused step.
-
-    Pallas-family impls on a dense cache only, and the projection must be a
-    single gemv K-step (d <= 512) so the in-kernel integer-dot + scale-after
-    order matches ``quant_gemv`` exactly.
-    """
-    from repro.core.packing import LANES
-
-    return (resolve_impl(impl) in ("pallas", "interpret")
-            and isinstance(layer, QuantizedKVLayer)
-            and d_model <= 512 and d_model % LANES[w_bits] == 0)
-
-
-def quant_kv_decode_step_proj(
-    x: jax.Array,                # (B, d) float — post-norm hidden, one token
-    w_packed: jax.Array,         # (N, d/lanes_w) int8 fused wqkv
-    w_scale: jax.Array,          # (1, N) f32
-    cos: jax.Array,              # (B, hd/2) f32 rope factors at pos
-    sin: jax.Array,
-    layer,                       # QuantizedKVLayer (dense only)
-    pos: jax.Array,
-    kv_valid: jax.Array,
-    *,
-    w_bits: int,
-    n_heads: int,
-    impl: str,
-    out_dtype=None,
-):
-    """Fused step with the skinny-M Q/K/V projection in the same dispatch.
-
-    Callers must pass the :func:`can_fuse_qkv` gate first.  Returns
-    ``(o (B, hq, hd), updated layer)``.
-    """
-    impl = _resolve(impl)
-    if impl not in ("pallas", "interpret"):
-        raise ValueError(f"proj-fused step needs a pallas impl, got {impl!r}")
-    b, s, n_kv, hd = layer.shape
-    g = n_heads // n_kv
-    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-    mask = jnp.where(kv_valid, 0.0, -1e30).astype(jnp.float32)
-    o, kb, ks, vb, vs = quant_kv_decode_step_proj_pallas(
-        pos, x, w_packed, w_scale, cos, sin, layer.k_packed, layer.k_scale,
-        layer.v_packed, layer.v_scale, mask, w_bits=w_bits, k_bits=layer.k_bits,
-        v_bits=layer.v_bits, n_heads=n_heads, hd=hd, block=layer.block,
-        interpret=impl == "interpret")
-    kp, ksc = place_block(layer.k_packed, layer.k_scale, kb, ks, pos,
-                          layer.block)
-    vp, vsc = place_block(layer.v_packed, layer.v_scale, vb, vs, pos,
-                          layer.block)
-    layer = dataclasses.replace(layer, k_packed=kp, k_scale=ksc,
-                                v_packed=vp, v_scale=vsc)
-    return o.reshape(b, n_kv * g, hd).astype(out_dtype or x.dtype), layer

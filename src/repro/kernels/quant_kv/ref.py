@@ -88,28 +88,20 @@ def quant_kv_decode_step_ref(
     kv_valid: jax.Array,          # (B, S) bool (already includes pos)
     *,
     out_dtype=None,
-    config: dict | None = None,
 ):
     """Fused append+attend fallback: one gather/requant feeds both halves.
 
     Bitwise-identical to ``quant_kv_append_ref`` → ``quant_kv_attention_ref``
-    for every config (the parity harness pins all of them): the requant math
-    is :func:`requantize_block_levels` (THE single source), placement writes
-    the same bytes whether by full-width select or per-slot dynamic-update
-    slice, and ``attend="substitute"`` splices the *pre-pack* levels into the
-    unpacked old cache — exact because pack→unpack round-trips on the
-    clipped signed grid.  What fusion buys on XLA-CPU is fewer dispatches:
-    the touched block is gathered and requantized once instead of once per
-    op, and substitute-mode attention no longer serializes behind the
-    packed-cache writeback.
+    (the parity harness pins it): the requant math is
+    :func:`requantize_block_levels` (THE single source), the touched block
+    is placed by a full-width select, and attention splices the *pre-pack*
+    levels into the unpacked old cache — exact because pack→unpack
+    round-trips on the clipped signed grid.  The touched block is gathered
+    and requantized once instead of once per op, and attention does not
+    serialize behind the packed-cache writeback.
 
-    ``config`` keys (see ``kernels/autotune.enumerate_candidates``):
-    ``place`` ∈ {"select", "dus"}, ``attend`` ∈ {"reunpack", "substitute"}.
     Returns ``(out (B, hq, hd), updated layer)``.
     """
-    cfg = config or {}
-    place = cfg.get("place", "select")
-    attend = cfg.get("attend", "substitute")   # measured default (autotunable)
     b, s, n_kv, hd = layer.shape
     hq = q.shape[1]
     g = hq // n_kv
@@ -131,25 +123,13 @@ def quant_kv_decode_step_ref(
         fp = lev.astype(jnp.float32) * sc_b
         lev_new, sc_new = requantize_block_levels(fp, new, off, bits)
         blk_new = packing.pack(lev_new, bits)                  # (B, H, block, hdp)
-        if place == "dus":
-            def one(pk, s_, b_, sn, bi):
-                pk2 = jax.lax.dynamic_update_slice_in_dim(pk, b_, bi * block,
-                                                          axis=1)
-                s2 = jax.lax.dynamic_update_slice_in_dim(s_, sn, bi, axis=1)
-                return pk2, s2
-            packed2, scale2 = jax.vmap(one)(packed, scale, blk_new, sc_new,
-                                            bidx)
-        else:
-            packed2 = jnp.where(at_block, blk_new[:, :, None],
-                                view).reshape(b, n_kv, s, hdp)
-            scale2 = jnp.where(at_block[..., 0], sc_new, scale)
-        if attend == "substitute":
-            lev_old = packing.unpack(packed, bits, hd).reshape(
-                b, n_kv, nb, block, hd)
-            lev_att = jnp.where(at_block, lev_new[:, :, None],
-                                lev_old).reshape(b, n_kv, s, hd)
-        else:
-            lev_att = packing.unpack(packed2, bits, hd)
+        packed2 = jnp.where(at_block, blk_new[:, :, None],
+                            view).reshape(b, n_kv, s, hdp)
+        scale2 = jnp.where(at_block[..., 0], sc_new, scale)
+        lev_old = packing.unpack(packed, bits, hd).reshape(
+            b, n_kv, nb, block, hd)
+        lev_att = jnp.where(at_block, lev_new[:, :, None],
+                            lev_old).reshape(b, n_kv, s, hd)
         return packed2, scale2, lev_att
 
     kp2, ks2, klev = side(layer.k_packed, layer.k_scale, kh, layer.k_bits)
@@ -195,15 +175,13 @@ def quant_kv_decode_step_paged_ref(
     kv_valid: jax.Array,          # (B, S)
     *,
     out_dtype=None,
-    config: dict | None = None,
 ):
     """Fused paged decode step: one pool gather + requant feeds both halves.
 
-    Bitwise-identical to ``append_token_paged`` → paged attention for both
-    configs.  ``attend="reunpack"`` literally re-gathers the updated pool
-    (the sequential graph); ``attend="substitute"`` gathers the *old* pool
-    and splices each slot's pre-pack levels into its own mapped touched
-    block, so attention no longer serializes behind the pool scatter.
+    Bitwise-identical to ``append_token_paged`` → paged attention.  It
+    gathers the *old* pool and splices each slot's pre-pack levels into its
+    own mapped touched block, so attention does not serialize behind the
+    pool scatter.
 
     Substitution relies on the engine's copy-on-write exclusivity: the
     block a live slot appends into is mapped by that slot alone, so no
@@ -213,8 +191,6 @@ def quant_kv_decode_step_paged_ref(
     """
     from repro.kvcache.paged import TRASH_BLOCK, to_dense
 
-    cfg = config or {}
-    attend = cfg.get("attend", "substitute")   # measured default (autotunable)
     b, s, n_kv, hd = layer.shape
     hq = q.shape[1]
     g = hq // n_kv
@@ -244,28 +220,22 @@ def quant_kv_decode_step_paged_ref(
                                        layer.v_bits)
     new_layer = dataclasses.replace(layer, k_packed=kp2, k_scale=ks2,
                                     v_packed=vp2, v_scale=vs2)
-    if attend == "substitute":
-        dense = to_dense(layer)                                # OLD contents
-        sel = ((jnp.arange(nb) == bidx[:, None])
-               & (raw >= 0)[:, None])[:, None, :, None, None]  # (B,1,nb,1,1)
+    dense = to_dense(layer)                                    # OLD contents
+    sel = ((jnp.arange(nb) == bidx[:, None])
+           & (raw >= 0)[:, None])[:, None, :, None, None]      # (B,1,nb,1,1)
 
-        def splice(packed, scale, lev_new, sc_new, bits):
-            lev_old = packing.unpack(packed, bits, hd).reshape(
-                b, n_kv, nb, block, hd)
-            lev = jnp.where(sel, lev_new[:, :, None],
-                            lev_old).reshape(b, n_kv, s, hd)
-            sc = jnp.where(sel[..., 0], sc_new, scale)
-            return lev, sc
+    def splice(packed, scale, lev_new, sc_new, bits):
+        lev_old = packing.unpack(packed, bits, hd).reshape(
+            b, n_kv, nb, block, hd)
+        lev = jnp.where(sel, lev_new[:, :, None],
+                        lev_old).reshape(b, n_kv, s, hd)
+        sc = jnp.where(sel[..., 0], sc_new, scale)
+        return lev, sc
 
-        klev, ks_att = splice(dense.k_packed, dense.k_scale, klev_new,
-                              ksc_new, layer.k_bits)
-        vlev, vs_att = splice(dense.v_packed, dense.v_scale, vlev_new,
-                              vsc_new, layer.v_bits)
-    else:
-        dense = to_dense(new_layer)
-        klev = packing.unpack(dense.k_packed, layer.k_bits, hd)
-        vlev = packing.unpack(dense.v_packed, layer.v_bits, hd)
-        ks_att, vs_att = dense.k_scale, dense.v_scale
+    klev, ks_att = splice(dense.k_packed, dense.k_scale, klev_new, ksc_new,
+                          layer.k_bits)
+    vlev, vs_att = splice(dense.v_packed, dense.v_scale, vlev_new, vsc_new,
+                          layer.v_bits)
     qg = q.astype(jnp.float32).reshape(b, n_kv, g, hd)
     o = _attention_from_levels(qg, klev, ks_att, vlev, vs_att, kv_valid,
                                block=block, hd=hd)

@@ -192,39 +192,6 @@ def attach_draft(artifact: PolicyArtifact, draft_policy, draft_k: int, *,
     return out
 
 
-def attach_kernel_configs(artifact: PolicyArtifact, cfg, *,
-                          block: int | None = None, impl: str | None = None,
-                          repeats: int = 20) -> PolicyArtifact:
-    """Return a copy of ``artifact`` carrying autotuned kernel configs (v5).
-
-    Runs the fused decode-step autotuner (``kernels.autotune``) over every
-    distinct ``(k_bits, v_bits)`` pair the artifact's state policy deploys,
-    at the geometry serving will actually use — ``cfg``'s KV heads/head_dim
-    and the cache ``block`` (the artifact's pool block when paged, else the
-    dense default).  The winning layouts ride the artifact so deployment
-    replays them instead of re-timing; every candidate is bitwise-
-    equivalent, so this phase can only change speed, never tokens.
-    """
-    if artifact.state_policy is None:
-        raise ValueError("kernel autotuning needs a state policy (the fused "
-                         "decode step only exists for quantized caches)")
-    from repro.kernels import autotune
-    from repro.kvcache import DEFAULT_BLOCK, resolve_state_bits
-
-    paged = artifact.pool is not None
-    if block is None:
-        block = int(artifact.pool["block"]) if paged else DEFAULT_BLOCK
-    state_bits = resolve_state_bits(artifact.state_policy, cfg)
-    entries = autotune.autotune_state(
-        state_bits, cfg.n_kv_heads, cfg.resolved_head_dim, block,
-        paged=paged, impl=impl, repeats=repeats)
-    out = dataclasses.replace(artifact, kernel_configs=entries,
-                              meta=dict(artifact.meta))
-    out.meta["kernel_autotune_impl"] = entries[0]["key"]["impl"] if entries \
-        else (impl or autotune.resolved_backend_impl())
-    return out
-
-
 def state_controller_config(n_entries: int) -> ControllerConfig:
     """Controller budgets for the post-training state phase.
 
@@ -304,14 +271,6 @@ def main(argv=None) -> int:
     ap.add_argument("--speculate-k", type=int, default=3,
                     help="--draft: tokens the draft proposes per verify step "
                          "(recorded in the artifact)")
-    # fused decode-step kernel autotuning (DESIGN.md §15)
-    ap.add_argument("--autotune-kernels", action="store_true",
-                    help="time the bitwise-equivalent fused decode-step "
-                         "layouts for every deployed (k_bits, v_bits) pair "
-                         "and record the winners in the artifact (v5) so "
-                         "serving replays them without re-search")
-    ap.add_argument("--autotune-repeats", type=int, default=20,
-                    help="--autotune-kernels: timing repetitions per candidate")
     # search-side observability (DESIGN.md §18)
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="record a Chrome/Perfetto trace of the whole search "
@@ -427,19 +386,6 @@ def main(argv=None) -> int:
             print(f"draft search failed ({metric} {draft_cost:g} vs deployed "
                   f"{dep_cost:g}, success={dres.success}); artifact carries "
                   f"no draft policy")
-
-    if args.autotune_kernels:
-        if artifact.state_policy is None:
-            ap.error("--autotune-kernels needs a state phase "
-                     "(--limit state_bytes=...)")
-        print("autotuning fused decode-step kernels ...")
-        artifact = attach_kernel_configs(artifact, cfg,
-                                         repeats=args.autotune_repeats)
-        for e in artifact.kernel_configs:
-            k = e["key"]
-            print(f"  {k['family']} k{k['k_bits']}/v{k['v_bits']} "
-                  f"[{k['impl']}]: {e['config']} ({e['micros']:g} us, "
-                  f"{e['candidates']} candidates)")
 
     if args.trace:
         tracer = obs_trace.get_tracer()

@@ -89,8 +89,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     if args.trace:
-        # enable BEFORE the engine builds so kernel-config replay and any
-        # autotuner activity land in the same trace as the decode steps
+        # enable before the engine builds, so the trace covers the whole run
         obs_trace.enable()
 
     cfg = get_config(args.arch)

@@ -566,57 +566,12 @@ def attention_decode_quant(
     """
     b = x.shape[0]
     positions = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1, 1), (b, 1))
-    if _can_fuse_step_proj(p, cfg, cache, bits, qimpl, x):
-        o, cache = _decode_step_proj_fused(p, x, cache, positions, cfg,
-                                           window=window, qimpl=qimpl)
-    else:
-        q, k_new, v_new = _qkv(p, x, cfg, positions, bits=bits, qimpl=qimpl)
-        o, cache = decode_attend_one(cache, q, k_new, v_new, pos, cfg,
-                                     window=window, qimpl=qimpl)
+    q, k_new, v_new = _qkv(p, x, cfg, positions, bits=bits, qimpl=qimpl)
+    o, cache = decode_attend_one(cache, q, k_new, v_new, pos, cfg,
+                                 window=window, qimpl=qimpl)
     o = o.astype(x.dtype)
     y = qdense(p["wo"], o.reshape(b, 1, -1), bits=_b(bits, "wo"), qimpl=qimpl)
     return y, cache
-
-
-def _can_fuse_step_proj(p, cfg, cache, bits, qimpl: str, x) -> bool:
-    """Gate for pulling the fused-wqkv GEMV into the fused decode step.
-
-    Pallas-family impls, dense quantized cache, fused ``wqkv``
-    QuantizedTensor leaf, default rope, no qk-norm, f32 activations in the
-    gemv fast-path batch regime, and no per-call bits override — every
-    condition the in-kernel projection needs to reproduce the composition's
-    numerics (kernels/quant_kv/kernel.py: _fused_step_proj_kernel).
-    """
-    from repro.kernels.quant_kv.ops import can_fuse_qkv
-
-    w = p.get("wqkv")
-    return (isinstance(w, QuantizedTensor)
-            and cfg.rope == "default" and not cfg.qk_norm
-            and _b(bits, "wqkv") is None
-            and x.dtype == jnp.float32 and x.shape[0] <= 8
-            and can_fuse_qkv(cache, cfg.d_model, w.bits, qimpl))
-
-
-def _decode_step_proj_fused(p, x, cache, positions, cfg, *, window: int,
-                            qimpl: str):
-    """Projection + rope + append + attend in the fused kernel dispatch."""
-    from repro.kernels.quant_kv.ops import quant_kv_decode_step_proj
-
-    b = x.shape[0]
-    pos = positions[:, 0]                                     # (B,)
-    skv = cache.seq
-    kv_valid = jnp.arange(skv)[None, :] <= pos[:, None]
-    if window:
-        kv_valid &= jnp.arange(skv)[None, :] > (pos[:, None] - window)
-    hd = cfg.resolved_head_dim
-    # same angle formula as apply_rope, evaluated at the one decode position
-    ang = pos[:, None].astype(jnp.float32) * rope_freqs(hd, cfg.rope_theta)
-    w = p["wqkv"]
-    o, cache = quant_kv_decode_step_proj(
-        x[:, 0], w.packed, w.scale, jnp.cos(ang), jnp.sin(ang), cache, pos,
-        kv_valid, w_bits=w.bits, n_heads=cfg.n_heads, impl=qimpl,
-        out_dtype=x.dtype)
-    return o[:, None], cache
 
 
 # ---------------------------------------------------------------------------

@@ -19,12 +19,14 @@ Design constraints, in priority order:
 
 Tracks (Perfetto "threads") are plain strings — ``"engine"`` for the serve
 loop's step-phase spans, ``"req/<uid>"`` for per-request queue waits,
-``"kernel"`` for autotuner timings — mapped to stable integer ``tid``s at
-record time and named via ``thread_name`` metadata on export.
+``obs.search.TRACK`` for the policy search's phases — mapped to stable
+integer ``tid``s at record time and named via ``thread_name`` metadata on
+export.
 
 The process-wide default tracer (:func:`get_tracer`) is what the serve
-engine, the autotuner, and the launchers share, so one ``enable()`` makes
-kernel searches and live decode steps land in the same trace file.
+engine, the search controller, and the launchers share, so one
+``enable()`` makes a search and live decode steps land in the same trace
+file.
 """
 from __future__ import annotations
 

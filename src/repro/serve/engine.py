@@ -317,11 +317,6 @@ class ServeEngine:
             surface = (kvcache.state_layer_infos(cfg, max_slots, max_seq)
                        if artifact.state_policy is not None else None)
             kvcache.verify_state_bits(self.state, artifact, surface=surface)
-        # autotuned fused decode-step configs (v5, DESIGN.md §15): validate
-        # the artifact table against THIS deployment's cache geometry and
-        # install it process-wide before any decode program traces, so
-        # serving replays the searched layouts instead of re-timing them
-        self._install_kernel_configs()
         # observability (DESIGN.md §16): the metrics registry is the source
         # of truth behind the legacy stats() dict; the process-wide tracer
         # adds step-phase, admission and queue-wait spans when (and only
@@ -419,43 +414,6 @@ class ServeEngine:
             self._chunk_step = jax.jit(chunk_step, donate_argnums=(1,))
         else:
             self._chunk_step = None
-
-    # -- autotuned kernel configs (DESIGN.md §15) --------------------------
-    def _install_kernel_configs(self) -> None:
-        """Replay a v5 artifact's autotuned fused decode-step configs.
-
-        Every recorded candidate is bitwise-equivalent, so a wrong table can
-        only cost speed — but a table tuned for a different cache geometry
-        means the artifact does not describe this deployment at all, which
-        is refused the same way a bitwidth mismatch is (``ArtifactError``).
-        Keys for bit pairs the deployed policy doesn't use are tolerated.
-        """
-        from repro.checkpoint.store import ArtifactError
-        from repro.kernels import autotune
-
-        entries = (self.artifact.kernel_configs
-                   if self.artifact is not None else None)
-        if not entries:
-            return
-        qlayers = [l for l in (self.state if isinstance(self.state, list) else [])
-                   if isinstance(l, (kvcache.QuantizedKVLayer,
-                                     kvcache.PagedKVLayer))]
-        if not qlayers:
-            raise ArtifactError(
-                "policy artifact carries kernel_configs but the engine built "
-                "a float decode state (no fused quantized decode step exists "
-                "to configure)")
-        lyr = qlayers[0]
-        try:
-            autotune.validate_configs(
-                entries, heads=lyr.shape[2], head_dim=lyr.shape[3],
-                block=lyr.block,
-                bit_pairs={(l.k_bits, l.v_bits) for l in qlayers})
-        except ValueError as e:
-            raise ArtifactError(
-                f"policy artifact kernel_configs do not fit this "
-                f"deployment: {e}") from e
-        autotune.set_active_configs(entries)
 
     # -- observability (DESIGN.md §16) ------------------------------------
     def _count(self, name: str, n: int = 1) -> None:

@@ -5,9 +5,12 @@ ONE parametrized sweep covers every (kernel family, impl, bits) cell:
     family ∈ quant_matmul / quant_gemv / quant_kv_attention / quant_kv_append
              / quant_kv_attention_paged / quant_kv_append_paged
              / quant_kv_decode_step / quant_kv_decode_step_paged
-             / quant_kv_decode_step_proj
     impl   ∈ interpret (the Pallas kernel body on CPU) / xla (the fallback)
     bits   ∈ VALID_BITS (2, 4, 6, 8)
+
+The two fused decode-step families also run at the served head layouts
+(``GEOMS``: head_dim 128, block 16, Yi-6B's and Phi-3-medium's KV heads
+and query groups), since the small base layout has head_dim 16.
 
 Every cell goes through the family's public *ops dispatch* and is checked
 against the family's ``ref.py`` oracle — so a new dispatch branch or a new
@@ -19,6 +22,8 @@ The second half property-tests ``core/packing`` round-trips across odd row
 counts and lane-boundary shapes (the deterministic hypothesis stand-in from
 conftest.py supplies the sweep).
 """
+import dataclasses
+
 import hypothesis
 import hypothesis.strategies as st
 import jax
@@ -54,46 +59,76 @@ def _rel(out, ref):
     return float(np.max(np.abs(out - ref)) / (np.max(np.abs(ref)) + 1e-12))
 
 
-def _dense_layer(bits, seed=0):
+def _rows(g, seed):
     rng = np.random.default_rng(seed)
-    k = jnp.asarray(rng.normal(size=(B, max(LENS), H, HD)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, max(LENS), H, HD)), jnp.float32)
-    layer = init_kv_layer(B, S, H, HD, k_bits=bits, v_bits=bits, block=BLOCK)
-    return insert_rows(layer, jnp.arange(B), k, v, jnp.asarray(LENS))
+    shape = (g.b, max(g.lens), g.h, g.hd)
+    return (jnp.asarray(rng.normal(size=shape), jnp.float32),
+            jnp.asarray(rng.normal(size=shape), jnp.float32))
 
 
-def _paged_layer(bits, seed=0):
+def _dense_layer(bits, g=None, seed=0):
+    g = g or BASE
+    k, v = _rows(g, seed)
+    layer = init_kv_layer(g.b, g.s, g.h, g.hd, k_bits=bits, v_bits=bits,
+                          block=g.block)
+    return insert_rows(layer, jnp.arange(g.b), k, v, jnp.asarray(g.lens))
+
+
+def _paged_layer(bits, g=None, seed=0):
     """Paged cache holding the SAME contents as :func:`_dense_layer`."""
-    rng = np.random.default_rng(seed)
-    k = jnp.asarray(rng.normal(size=(B, max(LENS), H, HD)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, max(LENS), H, HD)), jnp.float32)
-    layer = pg.init_paged_layer(3 * (S // BLOCK), B, S, H, HD, k_bits=bits,
-                                v_bits=bits, block=BLOCK)
-    pool = pg.BlockPool(3 * (S // BLOCK))
-    npb = -(-max(LENS) // BLOCK)
-    table = np.full((B, S // BLOCK), -1, np.int32)
-    rows = np.full((B, npb), -1, np.int32)
-    for b, length in enumerate(LENS):
-        for j in range(-(-(length + 1) // BLOCK)):  # cover the append at pos=len
+    g = g or BASE
+    k, v = _rows(g, seed)
+    nbs = g.s // g.block
+    layer = pg.init_paged_layer(g.b * nbs, g.b, g.s, g.h, g.hd, k_bits=bits,
+                                v_bits=bits, block=g.block)
+    pool = pg.BlockPool(g.b * nbs)
+    npb = -(-max(g.lens) // g.block)
+    table = np.full((g.b, nbs), -1, np.int32)
+    rows = np.full((g.b, npb), -1, np.int32)
+    for b, length in enumerate(g.lens):
+        for j in range(-(-(length + 1) // g.block)):  # cover the append at pos=len
             table[b, j] = pool.alloc()
             if j < npb:
                 rows[b, j] = table[b, j]
     layer = pg.with_table(layer, table)
-    return pg.insert_prefill_rows(layer, rows, k, v, jnp.asarray(LENS))
+    return pg.insert_prefill_rows(layer, rows, k, v, jnp.asarray(g.lens))
 
 
-def _query(seed=4):
+def _query(g=None, seed=4):
+    g = g or BASE
     rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.normal(size=(B, HQ, HD)), jnp.float32)
+    return jnp.asarray(rng.normal(size=(g.b, g.hq, g.hd)), jnp.float32)
 
 
-def _new_token(seed=1):
+def _new_token(g=None, seed=1):
+    g = g or BASE
     rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.normal(size=(B, 1, H, HD)), jnp.float32),
-            jnp.asarray(rng.normal(size=(B, 1, H, HD)), jnp.float32))
+    return (jnp.asarray(rng.normal(size=(g.b, 1, g.h, g.hd)), jnp.float32),
+            jnp.asarray(rng.normal(size=(g.b, 1, g.h, g.hd)), jnp.float32))
 
 
 KV_VALID = jnp.arange(S)[None, :] < jnp.asarray(LENS)[:, None]
+
+
+@dataclasses.dataclass(frozen=True)
+class Geom:
+    """A cache layout: slots, positions, KV heads, query heads, head_dim,
+    block, and each slot's filled length (where its append lands)."""
+    b: int
+    s: int
+    h: int
+    hq: int
+    hd: int
+    block: int
+    lens: tuple
+
+
+BASE = Geom(B, S, H, HQ, HD, BLOCK, LENS)
+#: the served layouts at a small slot count and length: Yi-6B (4 KV heads,
+#: groups of 8) and Phi-3-medium (10 KV heads, groups of 4); the appends
+#: land mid-block and on a block's last row
+GEOMS = {"yi6b": Geom(2, 64, 4, 32, 128, 16, (37, 15)),
+         "phi3m": Geom(2, 64, 10, 40, 128, 16, (50, 31))}
 
 
 # -- one runner per kernel family ------------------------------------------
@@ -196,17 +231,6 @@ def _run_kv_append_paged(impl, bits):
                                rtol=1e-5, atol=1e-5)
 
 
-def _step_configs(family: str, impl: str):
-    """Every tuned layout the autotuner could install for this cell, plus
-    None (the dispatcher default) — a config the parity sweep has not pinned
-    must never be enumerable."""
-    from repro.kernels import autotune
-
-    key = autotune.KernelKey(family=family, k_bits=4, v_bits=4, heads=H,
-                             head_dim=HD, block=BLOCK, impl=impl)
-    return [None, *autotune.enumerate_candidates(key)]
-
-
 def _assert_same_cache(got, want, tag):
     """Fused-vs-sequential caches must match BITWISE: packed levels AND
     scales (both paths run the identical requantize float sequence)."""
@@ -214,96 +238,35 @@ def _assert_same_cache(got, want, tag):
         assert jnp.array_equal(getattr(got, f), getattr(want, f)), (tag, f)
 
 
-def _run_kv_decode_step(impl, bits):
-    """Fused append+attend == sequential append -> attend, bitwise, for
-    every tuned layout candidate (kernels/autotune) at this impl."""
-    layer = _dense_layer(bits)
-    kn, vn = _new_token()
-    pos = jnp.asarray(LENS, jnp.int32)
-    valid = jnp.arange(S)[None, :] <= pos[:, None]
+def _run_step_vs_sequential(layer, g, impl):
+    """The fused decode step against append -> attend at the same impl."""
+    kn, vn = _new_token(g)
+    pos = jnp.asarray(g.lens, jnp.int32)
+    valid = jnp.arange(g.s)[None, :] <= pos[:, None]
     seq = kv_ops.quant_kv_append(layer, pos, kn, vn, impl=impl)
-    o_seq = kv_ops.quant_kv_attention(_query(), seq, valid, impl=impl,
+    o_seq = kv_ops.quant_kv_attention(_query(g), seq, valid, impl=impl,
                                       out_dtype=jnp.float32)
-    for cfg in _step_configs("decode_step", impl):
-        o, new = kv_ops.quant_kv_decode_step(
-            _query(), layer, pos, kn, vn, valid, impl=impl,
-            out_dtype=jnp.float32, config=cfg)
-        assert jnp.array_equal(o, o_seq), cfg
-        _assert_same_cache(new, seq, cfg)
+    o, new = kv_ops.quant_kv_decode_step(_query(g), layer, pos, kn, vn, valid,
+                                         impl=impl, out_dtype=jnp.float32)
+    assert jnp.array_equal(o, o_seq), impl
+    _assert_same_cache(new, seq, impl)
 
 
-def _run_kv_decode_step_paged(impl, bits):
+def _run_kv_decode_step(impl, bits, g=None):
+    """Fused append+attend == sequential append -> attend, bitwise."""
+    g = g or BASE
+    _run_step_vs_sequential(_dense_layer(bits, g), g, impl)
+
+
+def _run_kv_decode_step_paged(impl, bits, g=None):
     """Paged fused step vs sequential on the same pool — including an IDLE
     slot (fully unmapped table row): its append lands in the trash block in
     both paths, byte-for-byte (the engine parks free slots this way)."""
-    layer = _paged_layer(bits)
+    g = g or BASE
+    layer = _paged_layer(bits, g)
     tbl = np.asarray(layer.block_table).copy()
     tbl[1, :] = -1                      # slot 1 idle: every write -> trash
-    layer = pg.with_table(layer, jnp.asarray(tbl))
-    kn, vn = _new_token()
-    pos = jnp.asarray(LENS, jnp.int32)
-    valid = jnp.arange(S)[None, :] <= pos[:, None]
-    seq = kv_ops.quant_kv_append(layer, pos, kn, vn, impl=impl)
-    o_seq = kv_ops.quant_kv_attention(_query(), seq, valid, impl=impl,
-                                      out_dtype=jnp.float32)
-    for cfg in _step_configs("decode_step_paged", impl):
-        o, new = kv_ops.quant_kv_decode_step(
-            _query(), layer, pos, kn, vn, valid, impl=impl,
-            out_dtype=jnp.float32, config=cfg)
-        assert jnp.array_equal(o, o_seq), cfg
-        _assert_same_cache(new, seq, cfg)
-
-
-class _ProjCfg:
-    n_heads = HQ
-    n_kv_heads = H
-    resolved_head_dim = HD
-    rope = "default"
-    rope_theta = 10_000.0
-    qk_norm = False
-
-
-def _run_kv_decode_step_proj(impl, bits):
-    """Proj-fused step (gemv Q/K/V + rope in the same dispatch) against the
-    gemv -> rope -> sequential append/attend composition.
-
-    Cache buffers must be BITWISE equal (the K/V written through the fused
-    path feed every later step).  The attention output is allclose rather
-    than bitwise: the in-kernel projection dots a 1-row M block where
-    quant_gemv pads M to 8 rows, which can move the f32 dot by ~1 ulp
-    before the (exactly quantized) cache write.  The xla fallback has no
-    proj-fused kernel — the cell checks the dispatch gate refuses it.
-    """
-    from repro.models import layers as L
-
-    d_model = 64
-    if impl == "xla":
-        lyr = _dense_layer(bits)
-        assert not kv_ops.can_fuse_qkv(lyr, d_model, 4, impl)
-        return
-    key = jax.random.key(17 + bits)
-    x = jax.random.normal(jax.random.fold_in(key, 0), (B, d_model), jnp.float32)
-    w = jax.random.normal(jax.random.fold_in(key, 1),
-                          (d_model, (HQ + 2 * H) * HD), jnp.float32) * 0.05
-    wqkv = quantize_tensor(w, 4)
-    layer = _dense_layer(bits)
-    assert kv_ops.can_fuse_qkv(layer, d_model, wqkv.bits, impl)
-    pos = jnp.asarray(LENS, jnp.int32)
-    valid = jnp.arange(S)[None, :] <= pos[:, None]
-    cfg = _ProjCfg()
-    q4, kn, vn = L._qkv({"wqkv": wqkv}, x[:, None, :], cfg, pos[:, None],
-                        qimpl=impl)
-    seq = kv_ops.quant_kv_append(layer, pos, kn, vn, impl=impl)
-    o_seq = kv_ops.quant_kv_attention(q4[:, 0], seq, valid, impl=impl,
-                                      out_dtype=jnp.float32)
-    ang = pos[:, None].astype(jnp.float32) * L.rope_freqs(HD, cfg.rope_theta)
-    o, new = kv_ops.quant_kv_decode_step_proj(
-        x, wqkv.packed, wqkv.scale, jnp.cos(ang), jnp.sin(ang), layer, pos,
-        valid, w_bits=wqkv.bits, n_heads=HQ, impl=impl,
-        out_dtype=jnp.float32)
-    _assert_same_cache(new, seq, "proj")
-    np.testing.assert_allclose(np.asarray(o), np.asarray(o_seq),
-                               rtol=1e-6, atol=1e-6)
+    _run_step_vs_sequential(pg.with_table(layer, jnp.asarray(tbl)), g, impl)
 
 
 FAMILIES = {
@@ -315,16 +278,25 @@ FAMILIES = {
     "quant_kv_append_paged": _run_kv_append_paged,
     "quant_kv_decode_step": _run_kv_decode_step,
     "quant_kv_decode_step_paged": _run_kv_decode_step_paged,
-    "quant_kv_decode_step_proj": _run_kv_decode_step_proj,
 }
+
+#: (family, geometry) cases: every family at the base layout, and the fused
+#: decode steps again at each served layout
+CASES = ([pytest.param(f, None, id=f) for f in sorted(FAMILIES)]
+         + [pytest.param(f, geom, id=f"{f}-{geom}")
+            for f in ("quant_kv_decode_step", "quant_kv_decode_step_paged")
+            for geom in GEOMS])
 
 
 @pytest.mark.parametrize("bits", VALID_BITS)
 @pytest.mark.parametrize("impl", IMPLS)
-@pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_kernel_parity(family, impl, bits):
+@pytest.mark.parametrize("family,geom", CASES)
+def test_kernel_parity(family, geom, impl, bits):
     """Every (family, impl, bits) cell against the family's ref oracle."""
-    FAMILIES[family](impl, bits)
+    if geom is None:
+        FAMILIES[family](impl, bits)
+    else:
+        FAMILIES[family](impl, bits, GEOMS[geom])
 
 
 def test_sweep_is_exhaustive():
@@ -335,8 +307,7 @@ def test_sweep_is_exhaustive():
     assert {"quant_matmul", "quant_gemv", "quant_kv_attention",
             "quant_kv_append", "quant_kv_attention_paged",
             "quant_kv_append_paged", "quant_kv_decode_step",
-            "quant_kv_decode_step_paged",
-            "quant_kv_decode_step_proj"} == covered
+            "quant_kv_decode_step_paged"} == covered
 
 
 # ---------------------------------------------------------------------------

@@ -264,91 +264,6 @@ class TestEngineQuantizedState:
             verify_state_bits(state, art,
                               surface=state_layer_infos(other, 2, 64))
 
-class TestEngineKernelConfigs:
-    """v5 deploy path: the engine validates + installs a tuned kernel-config
-    table before tracing, and refuses tables tuned for a different cache
-    geometry (DESIGN.md §15)."""
-
-    def _entry(self, cfg, *, heads=None, family="decode_step"):
-        return {"key": {"family": family, "k_bits": 4, "v_bits": 4,
-                        "heads": heads or cfg.n_kv_heads,
-                        "head_dim": cfg.resolved_head_dim, "block": 16,
-                        "impl": "xla"},
-                "config": {"place": "dus", "attend": "reunpack"},
-                "micros": 1.0, "candidates": 4}
-
-    def _artifact(self, cfg, params, entries):
-        policy = BitPolicy.uniform(qapply.layer_specs(params, cfg), 8)
-        state_policy = BitPolicy.uniform(state_layer_infos(cfg, 2, 64), 4)
-        return PolicyArtifact.build(policy, backend="shift_add",
-                                    state_policy=state_policy,
-                                    kernel_configs=entries)
-
-    def test_engine_installs_and_replays_configs(self, dense_setup):
-        from repro.kernels import autotune
-
-        cfg, api, sp = dense_setup
-        params = api.init(cfg, jax.random.key(0))
-        entry = self._entry(cfg)
-        art = self._artifact(cfg, params, [entry])
-        qp = qapply.quantize_for_serve(sp, art, cfg)
-        prompts = [[5, 6, 7], [1, 2]]
-        try:
-            eng = ServeEngine(cfg, qp, max_slots=2, max_seq=64, artifact=art)
-            key = autotune.KernelKey.from_dict(entry["key"])
-            assert autotune.active_configs()[key] == entry["config"]
-            with_cfg = eng.generate(prompts, max_new_tokens=3)
-        finally:
-            autotune.set_active_configs(None)
-        # every tuned layout is bitwise-equivalent: tokens match an engine
-        # running the dispatcher default
-        plain = ServeEngine(cfg, qp, max_slots=2, max_seq=64,
-                            state_bits=art.state_policy)
-        assert with_cfg == plain.generate(prompts, max_new_tokens=3)
-
-    def test_mismatched_geometry_refused(self, dense_setup):
-        from repro.checkpoint.store import ArtifactError
-        from repro.kernels import autotune
-
-        cfg, api, sp = dense_setup
-        params = api.init(cfg, jax.random.key(0))
-        art = self._artifact(cfg, params,
-                             [self._entry(cfg, heads=cfg.n_kv_heads + 1)])
-        qp = qapply.quantize_for_serve(sp, art, cfg)
-        with pytest.raises(ArtifactError, match="tuned for geometry"):
-            ServeEngine(cfg, qp, max_slots=2, max_seq=64, artifact=art)
-        assert not autotune.active_configs()  # refused table never installs
-
-    def test_configs_without_quantized_state_refused(self, dense_setup):
-        from repro.checkpoint.store import ArtifactError
-
-        cfg, api, sp = dense_setup
-        params = api.init(cfg, jax.random.key(0))
-        policy = BitPolicy.uniform(qapply.layer_specs(params, cfg), 8)
-        art = PolicyArtifact.build(policy, backend="shift_add",
-                                   kernel_configs=[self._entry(cfg)])
-        qp = qapply.quantize_for_serve(sp, art, cfg)
-        with pytest.raises(ArtifactError, match="float decode state"):
-            ServeEngine(cfg, qp, max_slots=2, max_seq=64, artifact=art)
-
-    def test_extra_bit_pair_keys_tolerated(self, dense_setup):
-        """Keys for bit pairs the deployed policy doesn't use stay valid —
-        a policy edit must not invalidate the whole tuned table."""
-        from repro.kernels import autotune
-
-        cfg, api, sp = dense_setup
-        params = api.init(cfg, jax.random.key(0))
-        extra = self._entry(cfg)
-        extra["key"]["k_bits"] = extra["key"]["v_bits"] = 2
-        art = self._artifact(cfg, params, [self._entry(cfg), extra])
-        qp = qapply.quantize_for_serve(sp, art, cfg)
-        try:
-            eng = ServeEngine(cfg, qp, max_slots=2, max_seq=64, artifact=art)
-            assert len(autotune.active_configs()) == 2
-            assert eng.generate([[5, 6]], max_new_tokens=2)
-        finally:
-            autotune.set_active_configs(None)
-
 
 class TestEngineQuantizedStateDonation:
     def test_donation_still_holds_with_quantized_state(self, dense_setup):

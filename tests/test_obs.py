@@ -421,20 +421,6 @@ def test_lifecycle_spans_preempted_requeue(setup):
     assert evs[0][4] + evs[0][5] <= evs[1][4]
 
 
-def test_kernel_config_replay_traced(setup):
-    cfg, sp = setup
-    obs_trace.enable()
-    from repro.kernels import autotune
-    key = autotune.KernelKey(family="decode_step", k_bits=4, v_bits=4,
-                             heads=cfg.n_kv_heads,
-                             head_dim=cfg.resolved_head_dim, block=16,
-                             impl="xla")
-    autotune.autotune_key(key, batch=2, blocks=4, repeats=1)
-    tr = obs_trace.get_tracer()
-    names = [e[1] for e in _events_on(tr, "kernel")]
-    assert "autotune_candidate" in names and "autotune_winner" in names
-
-
 def test_tracing_overhead_bounded(setup):
     """Tracing must stay cheap: generous bound (3x + slack) so a noisy CI
     box never flakes, while a pathological per-span cost still fails."""

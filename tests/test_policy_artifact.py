@@ -107,36 +107,16 @@ class TestDraftPolicyV4:
         assert back.draft_policy is None and back.draft_k == 0
 
 
-class TestKernelConfigsV5:
-    """v5: autotuned fused decode-step kernel configs ride the artifact."""
-
-    ENTRY = {"key": {"family": "decode_step", "k_bits": 4, "v_bits": 4,
-                     "heads": 2, "head_dim": 16, "block": 8, "impl": "xla"},
-             "config": {"place": "dus", "attend": "substitute"},
-             "micros": 12.3, "candidates": 4}
-
-    def test_roundtrip_carries_kernel_configs(self):
-        art = make_artifact()
-        art5 = PolicyArtifact.build(art.policy, backend=art.backend,
-                                    kernel_configs=[self.ENTRY])
-        back = PolicyArtifact.from_json(art5.to_json())
-        assert back.version == ARTIFACT_VERSION == 6
-        assert back.kernel_configs == [self.ENTRY]
-
-    def test_build_rejects_malformed_entries(self):
-        art = make_artifact()
-        with pytest.raises(ValueError, match="needs 'key' and 'config'"):
-            PolicyArtifact.build(art.policy, kernel_configs=[{"key": {}}])
+class TestVersionLadder:
+    """Older artifact layouts load with their missing fields defaulted."""
 
     @pytest.mark.parametrize("version", [1, 2, 3, 4, 5])
-    def test_older_versions_load_without_kernel_configs(self, version):
+    def test_older_versions_load(self, version):
         """Every pre-v6 layout loads with its missing fields defaulted —
         the full backward-compat ladder in one sweep."""
         doc = json.loads(make_artifact().to_json())
         doc["artifact_version"] = version
         del doc["provenance"]
-        if version < 5:
-            del doc["kernel_configs"]
         if version < 4:
             del doc["draft_policy"], doc["draft_k"]
         if version < 3:
@@ -145,15 +125,35 @@ class TestKernelConfigsV5:
             del doc["state_policy"], doc["state_registry_hash"]
         back = PolicyArtifact.from_json(json.dumps(doc))
         assert back.version == version
-        assert back.kernel_configs is None
         assert back.provenance is None
         assert back.policy.bits == make_artifact().policy.bits
 
-    def test_attach_kernel_configs_needs_state_policy(self):
-        from repro.launch.search import attach_kernel_configs
-
-        with pytest.raises(ValueError, match="needs a state policy"):
-            attach_kernel_configs(make_artifact(), cfg=None)
+    @pytest.mark.parametrize("version", [5, 6])
+    def test_legacy_kernel_configs_field_is_dropped(self, version):
+        """A v5/v6 document that still carries the retired decode-step
+        layout table (``kernel_configs``) loads; the table is dropped and
+        the policy, state policy and provenance are unchanged."""
+        art = make_artifact()
+        state = BitPolicy.uniform(
+            (LayerInfo("blk0.state.k", (64, 16), macs=0, kind="state"),
+             LayerInfo("blk0.state.v", (64, 16), macs=0, kind="state")), 4)
+        prov = TestProvenanceV6.PROV if version == 6 else None
+        full = PolicyArtifact.build(art.policy, backend=art.backend,
+                                    state_policy=state, provenance=prov)
+        doc = json.loads(full.to_json())
+        doc["artifact_version"] = version
+        doc["kernel_configs"] = [
+            {"key": {"family": "decode_step", "k_bits": 4, "v_bits": 4,
+                     "heads": 2, "head_dim": 16, "block": 8, "impl": "xla"},
+             "config": {"place": "dus", "attend": "substitute"},
+             "micros": 12.3, "candidates": 4}]
+        back = PolicyArtifact.from_json(json.dumps(doc))
+        assert back.version == version
+        assert back.policy.bits == full.policy.bits
+        assert back.state_policy.bits == state.bits
+        assert back.state_registry_hash == full.state_registry_hash
+        assert back.provenance == prov
+        assert "kernel_configs" not in json.loads(back.to_json())
 
 
 class TestProvenanceV6:
